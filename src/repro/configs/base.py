@@ -60,10 +60,11 @@ class ModelConfig:
     scan_layers: bool = True
     fuse: str = "forge"  # none | forge  (Phase-2 pipeline on block bodies)
     # paged-KV attend implementation: "ref" gathers pages and reuses the
-    # unfused sdpa (bitwise vs the contiguous cache; the CPU/CI path),
-    # "pallas" dispatches kernels/paged_attention.py (TPU; auto-interprets
-    # off-TPU).  Only consulted by the paged decode/prefill entry points.
-    kv_kernel: str = "ref"  # ref | pallas
+    # unfused sdpa (bitwise vs the contiguous cache), "pallas" dispatches
+    # kernels/paged_attention.py compiled for the TPU, "interpret" runs
+    # that kernel in the Pallas interpreter (tests off the chip).  Only
+    # consulted by the paged decode/prefill entry points.
+    kv_kernel: str = "ref"  # ref | pallas | interpret
 
     # provenance
     source: str = ""  # [arXiv/hf ref; verification tier]

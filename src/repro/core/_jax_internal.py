@@ -1,34 +1,22 @@
 """Centralized imports of jax internals used by the Forge-UGC core.
 
 Everything version-sensitive lives here so the rest of the compiler only
-touches this module.  Verified against jax 0.8.x.
+touches this module.  Verified against jax 0.9.0 (the version
+``pyproject.toml`` pins).
 """
 from __future__ import annotations
 
-import jax
-
-try:  # jax >= 0.5
-    from jax._src.core import (
-        ClosedJaxpr,
-        Jaxpr,
-        JaxprEqn,
-        Literal,
-        Primitive,
-        ShapedArray,
-        Var,
-        eval_jaxpr,
-    )
-except ImportError:  # pragma: no cover - older layouts
-    from jax.core import (  # type: ignore
-        ClosedJaxpr,
-        Jaxpr,
-        JaxprEqn,
-        Literal,
-        Primitive,
-        ShapedArray,
-        Var,
-        eval_jaxpr,
-    )
+from jax._src.core import (
+    ClosedJaxpr,
+    Jaxpr,
+    JaxprEqn,
+    Literal,
+    Primitive,
+    ShapedArray,
+    Var,
+    eval_jaxpr,
+    trace_state_clean,
+)
 
 __all__ = [
     "ClosedJaxpr",
@@ -40,6 +28,7 @@ __all__ = [
     "Var",
     "eval_jaxpr",
     "jaxpr_as_fun",
+    "trace_state_clean",
 ]
 
 

@@ -34,10 +34,10 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 import jax
-import numpy as np
 
 from repro.runtime import chaos
 
+from .._jax_internal import trace_state_clean
 from ..bufalloc import allocate, segment_donations
 from ..executor import (
     AnalyzedProgram,
@@ -49,6 +49,13 @@ from ..executor import (
 )
 from ..lowering import RGIROp, RGIRProgram
 from .base import Backend, register_backend
+
+
+def _spec(aval: Any) -> jax.ShapeDtypeStruct:
+    """The abstract argument a segment program is compiled for."""
+    return jax.ShapeDtypeStruct(
+        aval.shape, aval.dtype, weak_type=getattr(aval, "weak_type", False)
+    )
 
 
 def _restore_segment_export(blob: bytes) -> Optional[Callable]:
@@ -286,38 +293,18 @@ class SegmentExecutor(BufferFilePoolMixin, PaddedExecutionMixin):
         self._static_peak = peak
         self._init_buffer_file(self.alloc.n_buffers, self._const_buf.items())
 
-        # AOT warmup: trigger XLA tracing/compilation of every accel
-        # segment now (compile-then-run), so build cost is paid here once
-        # — a compile-cache hit later skips real codegen, and the first
-        # serving request sees no jit-compile latency spike.  This calls
-        # the jitted fn on zero inputs rather than .lower().compile()
-        # because the AOT path does not populate jit's dispatch cache
-        # (measured on jax 0.4.37: first direct call after AOT compile
-        # still pays full compilation).  Zero arrays are shared across
-        # segments by (shape, dtype) — numpy-backed, so each segment call
-        # converts to a fresh device buffer and donation can never
-        # invalidate a shared zero — which caps the warmup transient at
-        # one host buffer per distinct aval instead of one per segment
-        # live-in (weights included).
+        # AOT warmup: lower and compile every accel segment from its
+        # live-in avals now (compile-then-run), so build cost is paid here
+        # once and the first serving request sees no jit-compile latency
+        # spike.  ``jit(...).lower(...).compile()`` fills the same
+        # executable cache that dispatch reads, and needs no data: no
+        # buffer the size of a weight is ever built for it.
         if warmup:
-            zeros_by_aval: Dict[Tuple[Tuple[int, ...], Any], np.ndarray] = {}
             for seg in self.segments:
-                if not seg.compiled:
-                    continue
-                try:
-                    zeros = []
-                    for r in seg.live_in:
-                        aval = reg_avals[r]
-                        key = (tuple(aval.shape), np.dtype(aval.dtype))
-                        z = zeros_by_aval.get(key)
-                        if z is None:
-                            z = zeros_by_aval.setdefault(
-                                key, np.zeros(key[0], key[1])
-                            )
-                        zeros.append(z)
-                    seg.fn(*zeros)
-                except Exception:  # exotic avals: fall back to lazy compile
-                    pass
+                if seg.compiled:
+                    seg.fn.lower(
+                        *(_spec(reg_avals[r]) for r in seg.live_in)
+                    ).compile()
 
         self.stats = ExecutorStats(
             n_instructions=n,
@@ -358,7 +345,7 @@ class SegmentExecutor(BufferFilePoolMixin, PaddedExecutionMixin):
         # donation is only legal on a clean trace state: jvp/vjp
         # linearization pushes *concrete* primal buffers through the
         # segment programs while keeping residual references to them
-        donate_ok = jax.core.trace_state_clean()
+        donate_ok = trace_state_clean()
         file, pool_hit = self._acquire_file()
         try:
             for b, v in zip(self._input_bufs, flat_inputs):

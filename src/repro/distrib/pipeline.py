@@ -93,36 +93,15 @@ def gpipe_apply(
 
     # manual only over the pod axis; (data, model) stay under GSPMD
     # inside each stage
-    fn = _shard_map_compat(
+    fn = jax.shard_map(
         per_pod,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        manual_axes={axis},
+        axis_names={axis},
+        check_vma=False,
     )
     return fn(stage_params, microbatches)
-
-
-def _shard_map_compat(f, *, mesh, in_specs, out_specs, manual_axes):
-    """shard_map across jax versions: ``jax.shard_map(axis_names=...)``
-    (jax>=0.8) vs ``jax.experimental.shard_map(auto=...)`` (older)."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                axis_names=set(manual_axes), check_vma=False,
-            )
-        except TypeError:
-            pass  # jax.shard_map exists but predates axis_names/check_vma
-    from jax.experimental.shard_map import shard_map as _sm
-
-    # no partial-manual mode on old jax (axis_index lowers to the
-    # unsupported PartitionId op there): go fully manual — unmentioned
-    # axes in the specs are simply replicated through the stage body
-    return _sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
 
 
 def reference_apply(stage_params, microbatches, stage_fn) -> jax.Array:

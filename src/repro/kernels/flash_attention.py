@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import ref as _ref
 
@@ -175,34 +176,16 @@ def _flash_forward(
         out_specs=pl.BlockSpec((1, bq, D), q_map),
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
         scratch_shapes=[
-            _vmem((bq, 1), jnp.float32),
-            _vmem((bq, 1), jnp.float32),
-            _vmem((bq, D), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, D), jnp.float32),
         ],
-        compiler_params=_tpu_params(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
         interpret=interpret,
     )(q3, k3, v3)
     return out.reshape(B, H, Sq, D)
-
-
-def _vmem(shape, dtype):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.VMEM(shape, dtype)
-    except Exception:  # pragma: no cover - non-TPU pallas builds
-        return pl.MemorySpace.ANY(shape, dtype)  # type: ignore
-
-
-def _tpu_params():
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
-    except Exception:  # pragma: no cover
-        return None
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))

@@ -30,8 +30,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import ref as _ref
 
@@ -85,26 +85,6 @@ def _shrink(block: int, dim: int) -> int:
     return max(b, 1)
 
 
-def _vmem(shape, dtype):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.VMEM(shape, dtype)
-    except Exception:  # pragma: no cover - non-TPU pallas builds
-        return pl.MemorySpace.ANY(shape, dtype)  # type: ignore
-
-
-def _tpu_params():
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
-    except Exception:  # pragma: no cover
-        return None
-
-
 def _forward(x, w, b, *, act, block_m, block_n, block_k, interpret):
     M, K = x.shape
     K2, N = w.shape
@@ -137,8 +117,10 @@ def _forward(x, w, b, *, act, block_m, block_n, block_k, interpret):
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda im, in_, ik: (im, in_)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        scratch_shapes=[_vmem((bm, bn), jnp.float32)],
-        compiler_params=_tpu_params(),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
         interpret=interpret,
     )(*inputs)
 

@@ -9,17 +9,23 @@ page ``k_pages[page_table[b, j]]`` HBM→VMEM.  The pages a row occupies
 can live anywhere in the pool — including pages shared with other rows
 via the prefix tree — and the kernel never materializes a gathered copy.
 
+Page layout: ``(num_pages, KVH, page_size, D)``, head-major inside a
+page, so one (KV head, page) pair is a contiguous ``(page_size, D)``
+tile.  Every block below spans the full trailing two dims of its array,
+which is what the TPU compiler's (8, 128) tiling rule accepts for any
+``page_size`` and head width.
+
 Design (decode step, one query token per row):
 
-* 3-D grid ``(batch, q_heads, max_pages)`` with the page axis innermost
+* 3-D grid ``(batch, kv_heads, max_pages)`` with the page axis innermost
   and ``arbitrary`` so the (m, l, acc) accumulator scratch carries across
   page iterations, exactly as flash_attention carries across KV blocks.
+* GQA without expanding K/V: the queries are viewed as
+  ``(B, KVH, groups, D)``, and one grid step scores all ``groups`` query
+  heads of a KV head against one page — a ``(groups, page_size)`` tile.
 * Scalar prefetch: ``page_table (B, MP)`` and ``pos (B,)`` ride in SMEM
   ahead of the grid; index maps read the table to pick the page, the
   kernel body reads ``pos`` to mask dead key slots.
-* GQA in the index maps: the query-head grid coordinate maps to its KV
-  head via ``h // groups`` (block size 1 on the KVH axis), as in
-  flash_attention — K/V are never expanded.
 * Page skip: pages strictly beyond ``pos`` (and, with a sliding window,
   pages wholly behind it) are skipped via ``pl.when``; the trash page
   (index 0) backing unallocated table entries is only ever touched by the
@@ -27,7 +33,8 @@ Design (decode step, one query token per row):
   slots beyond ``pos`` get an elementwise iota mask.
 
 Validated against :func:`repro.kernels.ref.paged_sdpa_ref` in interpret
-mode by ``tests/test_paged_kv.py`` over shape/GQA/window/pos sweeps.
+mode by ``tests/test_paged_kv.py`` over shape/GQA/window/pos sweeps, and
+compiled for a v5e by ``tests/test_tpu_compile.py``.
 """
 from __future__ import annotations
 
@@ -39,39 +46,21 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
-
-from . import ref as _ref
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = float(np.finfo(np.float32).min)
-
-try:  # pragma: no cover - exercised indirectly
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PLTPU = True
-except Exception:  # pragma: no cover - non-TPU pallas builds
-    pltpu = None
-    _HAVE_PLTPU = False
-
-
-def _tpu_params():
-    params_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    if params_cls is None:  # pragma: no cover
-        return None
-    return params_cls(dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _paged_kernel(
     pt_ref,   # (B, MP) int32 in SMEM (scalar prefetch)
     pos_ref,  # (B,)    int32 in SMEM (scalar prefetch)
-    q_ref,    # (1, 1, D)
-    k_ref,    # (1, ps, 1, D)
-    v_ref,    # (1, ps, 1, D)
-    o_ref,    # (1, 1, D)
-    m_scr,    # (1, 1) f32
-    l_scr,    # (1, 1) f32
-    acc_scr,  # (1, D) f32
+    q_ref,    # (1, 1, G, D)
+    k_ref,    # (1, 1, ps, D)
+    v_ref,    # (1, 1, ps, D)
+    o_ref,    # (1, 1, G, D)
+    m_scr,    # (G, 1) f32
+    l_scr,    # (G, 1) f32
+    acc_scr,  # (G, D) f32
     *,
     scale: float,
     page_size: int,
@@ -97,24 +86,24 @@ def _paged_kernel(
 
     @pl.when(run)
     def _body():
-        q = q_ref[0].astype(jnp.float32)  # (1, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (ps, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)  # (ps, d)
+        q = q_ref[0, 0].astype(jnp.float32)  # (G, d)
+        k = k_ref[0, 0].astype(jnp.float32)  # (ps, d)
+        v = v_ref[0, 0].astype(jnp.float32)  # (ps, d)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (1, ps)
+        )  # (G, ps)
         s = s * scale
-        col = lax.broadcasted_iota(jnp.int32, (1, page_size), 1) + k0
+        col = lax.broadcasted_iota(jnp.int32, s.shape, 1) + k0
         keep = col <= p
         if window is not None:
             keep = jnp.logical_and(keep, col > p - window)
         s = jnp.where(keep, s, _NEG_INF)
 
-        m_prev = m_scr[...]  # (1, 1)
+        m_prev = m_scr[...]  # (G, 1)
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)
-        prob = jnp.exp(s - m_new)  # (1, ps)
+        prob = jnp.exp(s - m_new)  # (G, ps)
         l_scr[...] = l_scr[...] * alpha + jnp.sum(prob, axis=1, keepdims=True)
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             prob, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
@@ -125,7 +114,7 @@ def _paged_kernel(
     def _finish():
         l = l_scr[...]
         l = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> 0 output
-        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def paged_attention(
@@ -141,28 +130,27 @@ def paged_attention(
 ) -> jax.Array:
     """Paged-attention decode step.  See module docstring.
 
-    q: (B, H, D); k_pages/v_pages: (num_pages, page_size, KVH, D);
+    q: (B, H, D); k_pages/v_pages: (num_pages, KVH, page_size, D);
     page_table: (B, max_pages) int32; pos: (B,) int32.  Returns (B, H, D).
+    ``interpret=True`` runs the kernel in the Pallas interpreter (CPU
+    validation); otherwise it is compiled for the TPU.
     """
     B, H, D = q.shape
-    NP, ps, KVH, Dk = k_pages.shape
-    assert D == Dk, (D, Dk)
-    assert H % KVH == 0, (H, KVH)
+    NP, KVH, ps, Dk = k_pages.shape
+    if D != Dk or H % KVH:
+        raise ValueError(
+            f"q {q.shape} does not match pages {k_pages.shape}"
+        )
     groups = H // KVH
     MP = page_table.shape[1]
     if scale is None:
         scale = 1.0 / (D ** 0.5)
 
-    if not _HAVE_PLTPU:  # pragma: no cover - non-TPU pallas builds
-        return _ref.paged_sdpa_ref(
-            q, k_pages, v_pages, page_table, pos, window=window, scale=scale
-        )
-
     def q_map(b, h, j, pt_ref, pos_ref):
-        return (b, h, 0)
+        return (b, h, 0, 0)
 
     def kv_map(b, h, j, pt_ref, pos_ref):
-        return (pt_ref[b, j], 0, h // groups, 0)
+        return (pt_ref[b, j], h, 0, 0)
 
     kernel = functools.partial(
         _paged_kernel,
@@ -173,30 +161,33 @@ def paged_attention(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, H, MP),
+        grid=(B, KVH, MP),
         in_specs=[
-            pl.BlockSpec((1, 1, D), q_map),
-            pl.BlockSpec((1, ps, 1, D), kv_map),
-            pl.BlockSpec((1, ps, 1, D), kv_map),
+            pl.BlockSpec((1, 1, groups, D), q_map),
+            pl.BlockSpec((1, 1, ps, D), kv_map),
+            pl.BlockSpec((1, 1, ps, D), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, D), q_map),
+        out_specs=pl.BlockSpec((1, 1, groups, D), q_map),
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, D), jnp.float32),
+            pltpu.VMEM((groups, 1), jnp.float32),
+            pltpu.VMEM((groups, 1), jnp.float32),
+            pltpu.VMEM((groups, D), jnp.float32),
         ],
     )
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-        compiler_params=_tpu_params(),
+        out_shape=jax.ShapeDtypeStruct((B, KVH, groups, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
         interpret=interpret,
     )(
         page_table.astype(jnp.int32),
         pos.astype(jnp.int32),
-        q,
+        q.reshape(B, KVH, groups, D),
         k_pages,
         v_pages,
     )
+    return out.reshape(B, H, D)

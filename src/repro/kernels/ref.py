@@ -56,20 +56,18 @@ def sdpa_ref(
 def gather_pages(pages: jax.Array, page_table: jax.Array) -> jax.Array:
     """Gather a contiguous per-row KV view out of a paged store.
 
-    pages: (num_pages, page_size, KVH, D) — the flat page pool.
+    pages: (num_pages, KVH, page_size, D) — the flat page pool.
     page_table: (B, max_pages) int32 — per-row page indices; unallocated
     entries point at the trash page (0) and are masked out by the caller.
 
     Returns (B, KVH, max_pages * page_size, D), the same layout a
     contiguous cache row would have.
     """
-    NP, ps, KVH, D = pages.shape
+    NP, KVH, ps, D = pages.shape
     B, MP = page_table.shape
-    flat = pages.reshape(NP * ps, KVH, D)
-    sl = jnp.arange(MP * ps, dtype=jnp.int32)
-    rows = page_table[:, sl // ps].astype(jnp.int32) * ps + sl % ps  # (B, L)
-    view = jnp.take(flat, rows, axis=0)  # (B, L, KVH, D)
-    return view.transpose(0, 2, 1, 3)
+    view = jnp.take(pages, page_table.astype(jnp.int32), axis=0)
+    # (B, MP, KVH, ps, D) -> (B, KVH, MP * ps, D)
+    return view.transpose(0, 2, 1, 3, 4).reshape(B, KVH, MP * ps, D)
 
 
 def paged_sdpa_ref(
@@ -85,12 +83,12 @@ def paged_sdpa_ref(
     """Reference paged-attention decode step (the kernel's fidelity oracle).
 
     q: (B, H, D) — one query token per row; k_pages/v_pages:
-    (num_pages, page_size, KVH, D); page_table: (B, max_pages) int32;
+    (num_pages, KVH, page_size, D); page_table: (B, max_pages) int32;
     pos: (B,) int32 — the query's position (keys at indices <= pos are
     live; garbage beyond pos, including trash-page reads, is masked).
     Returns (B, H, D).
     """
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[2]
     MP = page_table.shape[1]
     L = MP * ps
     k = gather_pages(k_pages, page_table)

@@ -28,8 +28,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import ref as _ref
 
@@ -49,7 +49,7 @@ def _block_scan(x_ref, a_ref, h0_ref, carry_scr, *, block_t):
 
     @pl.when(it == 0)
     def _init():
-        carry_scr[...] = h0_ref[...].astype(jnp.float32)
+        carry_scr[...] = h0_ref[0].astype(jnp.float32)
 
     x = x_ref[0].astype(jnp.float32)  # (bt, bd)
     a = a_ref[0].astype(jnp.float32)  # (bt, bd)
@@ -80,31 +80,11 @@ def _rg_lru_chunk_kernel(x_ref, a_ref, h0_ref, o_ref, last_ref, carry_scr,
                          *, block_t):
     out = _block_scan(x_ref, a_ref, h0_ref, carry_scr, block_t=block_t)
     o_ref[0] = out.astype(o_ref.dtype)
-    # every T-block writes the same (1, bd) output block; T is the
+    # every T-block writes the same (1, 1, bd) output block; T is the
     # innermost *sequential* grid axis, so the final block's write wins
     # and ``last_ref`` leaves the kernel holding h[T-1] — the carry the
     # caller folds into the next chunk's h0
-    last_ref[...] = out[-1:, :].astype(last_ref.dtype)
-
-
-def _vmem(shape, dtype):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.VMEM(shape, dtype)
-    except Exception:  # pragma: no cover
-        return pl.MemorySpace.ANY(shape, dtype)  # type: ignore
-
-
-def _tpu_params():
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
-    except Exception:  # pragma: no cover
-        return None
+    last_ref[0] = out[-1:, :].astype(last_ref.dtype)
 
 
 def _shrink(block: int, dim: int) -> int:
@@ -114,7 +94,14 @@ def _shrink(block: int, dim: int) -> int:
     return max(b, 1)
 
 
-def _forward(x, a, h0, *, block_t, block_d, interpret):
+def _pallas_scan(x, a, h0, *, block_t, block_d, interpret, with_last):
+    """One ``pallas_call`` over the ``(B, D/bd, T/bt)`` grid.
+
+    The carry-in ``h0`` (and the ``h_last`` output of the chunked form)
+    travel as ``(B, 1, D)``: a ``(1, 1, bd)`` block then spans the full
+    second-minor dim, as the TPU compiler's tiling rule requires, where
+    a ``(1, bd)`` block over ``(B, D)`` would not.
+    """
     B, T, D = x.shape
     bt = _shrink(block_t, T)
     bd = _shrink(block_d, D)
@@ -123,65 +110,38 @@ def _forward(x, a, h0, *, block_t, block_d, interpret):
     def xa_map(b, id_, it):
         return (b, it, id_)
 
-    def h0_map(b, id_, it):
-        return (b, id_)
+    def h_map(b, id_, it):
+        return (b, 0, id_)
 
-    kernel = functools.partial(_rg_lru_kernel, block_t=bt)
-    return pl.pallas_call(
-        kernel,
+    seq_spec = pl.BlockSpec((1, bt, bd), xa_map)
+    h_spec = pl.BlockSpec((1, 1, bd), h_map)
+    seq_shape = jax.ShapeDtypeStruct((B, T, D), x.dtype)
+    body = _rg_lru_chunk_kernel if with_last else _rg_lru_kernel
+    out = pl.pallas_call(
+        functools.partial(body, block_t=bt),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bt, bd), xa_map),
-            pl.BlockSpec((1, bt, bd), xa_map),
-            pl.BlockSpec((1, bd), h0_map),
-        ],
-        out_specs=pl.BlockSpec((1, bt, bd), xa_map),
-        out_shape=jax.ShapeDtypeStruct((B, T, D), x.dtype),
-        scratch_shapes=[_vmem((1, bd), jnp.float32)],
-        compiler_params=_tpu_params(),
+        in_specs=[seq_spec, seq_spec, h_spec],
+        out_specs=[seq_spec, h_spec] if with_last else seq_spec,
+        out_shape=(
+            [seq_shape, jax.ShapeDtypeStruct((B, 1, D), x.dtype)]
+            if with_last else seq_shape
+        ),
+        scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
         interpret=interpret,
-    )(x, a, h0)
-
-
-def _forward_chunk(x, a, h0, *, block_t, block_d, interpret):
-    B, T, D = x.shape
-    bt = _shrink(block_t, T)
-    bd = _shrink(block_d, D)
-    grid = (B, D // bd, T // bt)
-
-    def xa_map(b, id_, it):
-        return (b, it, id_)
-
-    def h0_map(b, id_, it):
-        return (b, id_)
-
-    kernel = functools.partial(_rg_lru_chunk_kernel, block_t=bt)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bt, bd), xa_map),
-            pl.BlockSpec((1, bt, bd), xa_map),
-            pl.BlockSpec((1, bd), h0_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bt, bd), xa_map),
-            pl.BlockSpec((1, bd), h0_map),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, T, D), x.dtype),
-            jax.ShapeDtypeStruct((B, D), x.dtype),
-        ],
-        scratch_shapes=[_vmem((1, bd), jnp.float32)],
-        compiler_params=_tpu_params(),
-        interpret=interpret,
-    )(x, a, h0)
+    )(x, a, h0.reshape(B, 1, D))
+    if with_last:
+        h, last = out
+        return h, last.reshape(B, D)
+    return out
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _rg_lru_vjp(x, a, h0, block_t, block_d, interpret):
-    return _forward(x, a, h0, block_t=block_t, block_d=block_d,
-                    interpret=interpret)
+    return _pallas_scan(x, a, h0, block_t=block_t, block_d=block_d,
+                        interpret=interpret, with_last=False)
 
 
 def _fwd(x, a, h0, block_t, block_d, interpret):
@@ -204,8 +164,8 @@ _rg_lru_vjp.defvjp(_fwd, _bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _rg_lru_chunk_vjp(x, a, h0, block_t, block_d, interpret):
-    return _forward_chunk(x, a, h0, block_t=block_t, block_d=block_d,
-                          interpret=interpret)
+    return _pallas_scan(x, a, h0, block_t=block_t, block_d=block_d,
+                        interpret=interpret, with_last=True)
 
 
 def _fwd_chunk(x, a, h0, block_t, block_d, interpret):

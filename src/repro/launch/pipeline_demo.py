@@ -18,10 +18,11 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..distrib.pipeline import gpipe_apply, reference_apply, split_stages
+from .mesh import make_mesh
 
 
 def main() -> int:
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     L, d, mb, M, S = 4, 32, 2, 4, 8
     rng = np.random.default_rng(0)
     blocks = {

@@ -45,6 +45,7 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
+from pathlib import Path
 from typing import Any, Dict, List, Optional, Sequence
 
 import jax
@@ -67,22 +68,32 @@ from .steps import (
 )
 
 
-def _enable_jax_persistent_cache(cache_dir: str) -> None:
-    """Point XLA's own persistent compilation cache under ``cache_dir``.
+#: JAX's persistent compilation cache when ``JAX_COMPILATION_CACHE_DIR``
+#: is unset: one fixed directory at the root of the checkout, so every
+#: process run from it finds what an earlier one compiled
+DEFAULT_JAX_CACHE_DIR = str(Path(__file__).resolve().parents[3] / ".jax_cache")
 
-    The Forge disk store replays Phase 4a-c analysis + ``jax.export``
-    blobs, but deserialized segment executables (and any segments that
-    fell back to fresh tracing) still lower through XLA — this second
-    tier keeps *those* XLA compiles off the restart path too.
-    Best-effort: jaxlibs without the flags keep serving without it.
+
+def setup_jax_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` decides where it is set (JAX reads it
+    at import); otherwise :data:`DEFAULT_JAX_CACHE_DIR`.  Call it before
+    the first compile: the cache binds its directory on first use, so a
+    later move resets it.  Raises ``OSError`` when the directory cannot
+    be created or written.  The Forge disk store (``--cache-dir``) is a
+    separate tier and never moves this one.
     """
-    try:
-        jax.config.update(
-            "jax_compilation_cache_dir", os.path.join(cache_dir, "xla")
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    except Exception:
-        pass
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_JAX_CACHE_DIR
+    os.makedirs(path, exist_ok=True)
+    if not os.access(path, os.W_OK):
+        raise PermissionError(f"JAX compilation cache {path} is not writable")
+    if jax.config.jax_compilation_cache_dir != path:
+        from jax.experimental.compilation_cache import compilation_cache
+
+        jax.config.update("jax_compilation_cache_dir", path)
+        compilation_cache.reset_cache()
+    return path
 
 
 class BatchedServer:
@@ -144,6 +155,7 @@ class BatchedServer:
                  kv_page_size: int = 16, kv_pages: Optional[int] = None,
                  async_compile: bool = False, compile_workers: int = 2,
                  cache_dir: Optional[str] = None):
+        setup_jax_compile_cache()
         self.cfg = cfg
         self.params = params
         self.model = get_model(cfg)
@@ -231,7 +243,6 @@ class BatchedServer:
             g = get_compile_cache()
             if g.store is None:
                 g.store = store
-            _enable_jax_persistent_cache(str(cache_dir))
         self._front_lock = threading.Lock()
         #: donating zero-fill: recycles a pooled KV cache's device buffers
         #: in place instead of allocating a fresh bucket-sized pytree
@@ -2537,11 +2548,13 @@ def main(argv=None) -> int:
                     help="page-pool size incl. the reserved trash page "
                          "(--paged; 0 = eight full-length slots' worth)")
     ap.add_argument("--kv-kernel", default="ref",
-                    choices=["ref", "pallas"],
+                    choices=["ref", "pallas", "interpret"],
                     help="paged attend implementation (--paged): ref = "
                          "page gather + unfused sdpa (bitwise vs the "
                          "contiguous cache), pallas = the paged-"
-                         "attention decode kernel (interpreted off-TPU)")
+                         "attention decode kernel compiled for the TPU, "
+                         "interpret = that kernel in the Pallas "
+                         "interpreter")
     ap.add_argument("--async-compile", action="store_true",
                     help="compile cold buckets on a background worker "
                          "pool; dispatches pad into the nearest warm "
@@ -2551,10 +2564,13 @@ def main(argv=None) -> int:
                     help="background compile worker threads "
                          "(--async-compile)")
     ap.add_argument("--cache-dir", default=None,
-                    help="persistent on-disk compile cache: bucket "
+                    help="persistent on-disk Forge compile store: bucket "
                          "programs (Phase 4a-c analysis + serialized "
                          "segment executables) replay across process "
-                         "restarts (--mode forge)")
+                         "restarts (--mode forge).  JAX's own "
+                         "compilation cache is placed by "
+                         "JAX_COMPILATION_CACHE_DIR, else .jax_cache/ at "
+                         "the checkout root")
     ap.add_argument("--assert-no-builds", action="store_true",
                     help="exit nonzero if any full Phase 1-4 build ran "
                          "(compile-cache miss count > 0) — the CI "
@@ -2602,6 +2618,7 @@ def main(argv=None) -> int:
         except ValueError as e:
             ap.error(str(e))
 
+    setup_jax_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     if cfg.family == "encdec":
         raise SystemExit("use examples/ for enc-dec serving")
@@ -2609,7 +2626,9 @@ def main(argv=None) -> int:
         cfg = cfg.with_(kv_kernel=args.kv_kernel)
     model = get_model(cfg)
     key = jax.random.PRNGKey(args.seed)
-    params = model.init(key, cfg)
+    # one program: no float32 draw of a stacked weight sits beside the
+    # bf16 parameters, as it would op by op
+    params = jax.jit(model.init, static_argnums=1)(key, cfg)
     rng = np.random.default_rng(args.seed)
 
     server = BatchedServer(cfg, params, max_len=args.max_len, mode=args.mode,

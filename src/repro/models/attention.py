@@ -24,6 +24,11 @@ from . import layers as L
 
 Params = Dict[str, Any]
 
+#: paged-cache attend implementations: "ref" gathers the row's pages and
+#: reuses the unfused sdpa; "pallas" is the paged decode kernel compiled
+#: for the TPU; "interpret" is that kernel in the Pallas interpreter
+KV_KERNELS = ("ref", "pallas", "interpret")
+
 
 def attn_init(
     key,
@@ -111,7 +116,7 @@ def _paged_update_attend(
     *,
     window: Optional[int],
     write_mask: Optional[jax.Array],  # bool (B,) — rows allowed to write
-    kv_kernel: str,  # "ref" (gather + unfused sdpa) | "pallas"
+    kv_kernel: str,  # one of KV_KERNELS
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Paged-cache decode/prefill: scatter this step's K/V into the flat
     page pool through the page table, then attend over the row's pages.
@@ -125,14 +130,16 @@ def _paged_update_attend(
     path is **bitwise** the contiguous path on live rows (garbage beyond
     ``pos``, trash reads included, lands on score columns already pinned
     to the additive-mask floor).  "pallas" dispatches the page-table-
-    indirected decode kernel instead (see kernels/paged_attention.py).
+    indirected decode kernel instead (see kernels/paged_attention.py),
+    compiled for the TPU; "interpret" runs that kernel in the Pallas
+    interpreter, for tests off the chip.
     """
     from ..kernels.paged_attention import paged_attention as _paged_kernel
     from ..kernels.ref import gather_pages as _gather_pages
 
     k_pages, v_pages = cache["k_pages"], cache["v_pages"]
     pt = cache["page_table"].astype(jnp.int32)
-    NP, ps, KVH, D = k_pages.shape
+    NP, KVH, ps, D = k_pages.shape
     B, MP = pt.shape
     max_len = MP * ps
     sq = q.shape[2]
@@ -141,27 +148,26 @@ def _paged_update_attend(
     pos_row = jnp.broadcast_to(pos_arr, (B,)) if pos_arr.ndim == 0 else pos_arr
     abs_pos = pos_row[:, None] + jnp.arange(sq, dtype=jnp.int32)[None, :]
     page_idx = jnp.clip(abs_pos // ps, 0, MP - 1)
-    slot = jnp.take_along_axis(pt, page_idx, axis=1) * ps + abs_pos % ps
     ok = abs_pos < max_len
     if write_mask is not None:
         ok = jnp.logical_and(ok, write_mask[:, None])
     # trash-routed writes may collide (last-writer-wins): trash content is
     # never unmasked, live destinations are uniquely owned per (row, pos)
-    dest = jnp.where(ok, slot, abs_pos % ps).reshape(-1)
+    page = jnp.where(
+        ok, jnp.take_along_axis(pt, page_idx, axis=1), 0
+    ).reshape(-1)
+    off = (abs_pos % ps).reshape(-1)
     k_tok = k.transpose(0, 2, 1, 3).reshape(B * sq, KVH, D)
     v_tok = v.transpose(0, 2, 1, 3).reshape(B * sq, KVH, D)
-    new_k = k_pages.reshape(NP * ps, KVH, D).at[dest].set(k_tok).reshape(
-        k_pages.shape
-    )
-    new_v = v_pages.reshape(NP * ps, KVH, D).at[dest].set(v_tok).reshape(
-        v_pages.shape
-    )
+    new_k = k_pages.at[page, :, off].set(k_tok)
+    new_v = v_pages.at[page, :, off].set(v_tok)
 
-    if kv_kernel == "pallas" and sq == 1:
-        interpret = jax.default_backend() != "tpu"
+    if kv_kernel not in KV_KERNELS:
+        raise ValueError(f"kv_kernel must be one of {KV_KERNELS}, got {kv_kernel!r}")
+    if kv_kernel != "ref" and sq == 1:
         out = _paged_kernel(
             q[:, :, 0, :], new_k, new_v, pt, pos_row,
-            window=window, interpret=interpret,
+            window=window, interpret=kv_kernel == "interpret",
         )[:, :, None, :].astype(v.dtype)
     else:
         # must mirror the contiguous cache branch of attention() exactly:

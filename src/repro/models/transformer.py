@@ -149,7 +149,7 @@ def block_decode(
 def block_paged_decode(
     p: Params,
     x: jax.Array,
-    k_pages: jax.Array,  # (num_pages, page_size, KVH, D) — this layer's pool
+    k_pages: jax.Array,  # (num_pages, KVH, page_size, D) — this layer's pool
     v_pages: jax.Array,
     page_table: jax.Array,  # (B, max_pages) int32, shared by all layers
     pos: jax.Array,  # scalar or per-row (B,) write position
@@ -197,7 +197,7 @@ def _body_fn(cfg: ModelConfig, mode: str, example_args) -> Any:
         # the pallas kernel is itself the fused dispatch: capturing a
         # pallas_call through the Phase-1 tracer buys nothing and the
         # passes don't know the primitive — run the body raw
-        enabled = enabled and cfg.kv_kernel != "pallas"
+        enabled = enabled and cfg.kv_kernel == "ref"
         mode = f"{mode}[{cfg.kv_kernel}]"  # keep body-cache keys distinct
     else:
         base = block_apply if mode == "apply" else block_decode
@@ -291,7 +291,7 @@ def init_paged_cache(
     if max_len % page_size:
         raise ValueError(f"max_len {max_len} not a multiple of page_size {page_size}")
     dt = _dtype(cfg)
-    shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim_)
+    shape = (cfg.n_layers, num_pages, cfg.n_kv_heads, page_size, cfg.head_dim_)
     return {
         "k_pages": jnp.zeros(shape, dt),
         "v_pages": jnp.zeros(shape, dt),

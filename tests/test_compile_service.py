@@ -550,3 +550,49 @@ class TestServeAsync:
         t1 = srv1.generate(prompts, 4)["tokens"]
         t2 = srv2.generate(prompts, 4)["tokens"]
         np.testing.assert_array_equal(t1, t2)
+
+
+class TestJaxCachePlacement:
+    """JAX's persistent compilation cache sits where
+    ``JAX_COMPILATION_CACHE_DIR`` says, else at one fixed directory in
+    the checkout; the Forge ``cache_dir`` store never moves it."""
+
+    @pytest.fixture(autouse=True)
+    def _restore_jax_cache_dir(self):
+        from jax.experimental.compilation_cache import compilation_cache
+
+        before = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", before)
+        compilation_cache.reset_cache()
+
+    def test_env_var_decides(self, smoke_setup, tmp_path, monkeypatch):
+        from repro.launch.serve import BatchedServer
+
+        cfg, params = smoke_setup
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
+        BatchedServer(cfg, params, max_len=64, mode="forge")
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path / "xla")
+        assert os.path.isdir(tmp_path / "xla")
+
+    def test_unset_uses_fixed_checkout_path(self, smoke_setup, monkeypatch):
+        from repro.launch import serve
+
+        cfg, params = smoke_setup
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        serve.BatchedServer(cfg, params, max_len=64, mode="forge")
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert serve.DEFAULT_JAX_CACHE_DIR == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == serve.DEFAULT_JAX_CACHE_DIR
+
+    def test_forge_cache_dir_leaves_jax_cache_alone(
+        self, smoke_setup, tmp_path, monkeypatch
+    ):
+        from repro.launch.serve import BatchedServer
+
+        cfg, params = smoke_setup
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
+        BatchedServer(cfg, params, max_len=64, mode="forge",
+                      cache_dir=str(tmp_path / "forge"))
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path / "xla")
+        assert not os.path.exists(tmp_path / "forge" / "xla")

@@ -10,8 +10,10 @@ its buffer onto — and donated-path outputs must match the unscheduled,
 unallocated ``reference`` oracle.
 """
 import gc
+import logging
 import threading
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -266,8 +268,10 @@ class TestDispatchPlans:
 
 class TestWarmupDedup:
     def test_warmup_zeros_shared_by_aval(self, block_fn, block_args,
-                                         monkeypatch):
-        """AOT warmup builds at most one zero array per distinct aval."""
+                                         monkeypatch, caplog):
+        """AOT warmup compiles every accel segment from its avals alone:
+        it builds no zero array for any live-in (weights included), and
+        the first real call then compiles nothing."""
         import repro.core.backends.segment_jit as sj
 
         calls = []
@@ -277,26 +281,30 @@ class TestWarmupDedup:
             calls.append(a)
             return real_zeros(*a, **kw)
 
-        monkeypatch.setattr(sj.np, "zeros", counting_zeros)
+        monkeypatch.setattr(np, "zeros", counting_zeros)
         prog = _block_prog(block_fn, block_args)
         ex = SegmentExecutor(analyze_program(prog), warmup=True)
-        # patching np.zeros is global: keep only the warmup's own calls
-        # (``np.zeros(shape_tuple, dtype)`` — two positional args)
-        calls = [
-            a for a in calls
-            if len(a) == 2 and isinstance(a[0], tuple)
-            and isinstance(a[1], np.dtype)
-        ]
-        distinct = {
-            (tuple(prog.reg_avals[r].shape), str(prog.reg_avals[r].dtype))
+        monkeypatch.setattr(np, "zeros", real_zeros)
+        live_in_avals = {
+            (tuple(prog.reg_avals[r].shape), np.dtype(prog.reg_avals[r].dtype))
             for seg in ex.segments if seg.compiled
             for r in seg.live_in
         }
-        total_live_ins = sum(
-            len(seg.live_in) for seg in ex.segments if seg.compiled
-        )
-        assert len(calls) <= len(distinct)
-        assert total_live_ins > len(distinct)  # dedup actually saved builds
+        assert live_in_avals, "the block must have compiled segments"
+        built = [a for a in calls if a and tuple(np.shape(a[0])) in
+                 {shape for shape, _ in live_in_avals}]
+        assert built == [], "warmup built arrays for segment live-ins"
+        assert not hasattr(sj, "np"), "segment_jit needs no host arrays"
+
+        # host segments replay op by op and compile their eager ops on
+        # first use; the accel segment programs must already be built
+        with jax.log_compiles(True), caplog.at_level(
+            logging.WARNING, logger="jax._src.interpreters.pxla"
+        ):
+            ex.execute(*block_args)
+        late = [r.getMessage() for r in caplog.records
+                if "jit(seg_fn)" in r.getMessage()]
+        assert late == [], "a warmed segment compiled on first call"
 
 
 class TestBufferPool:

@@ -125,16 +125,18 @@ class TestActivationPolicy:
 
     def test_policy_filters_kinds(self):
         from repro.distrib.actsharding import ActivationPolicy
+        from repro.launch.mesh import make_mesh
 
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         pol = ActivationPolicy(mesh=mesh, only=frozenset({"logits"}))
         assert pol.spec_for("heads", (2, 4, 8, 16)) is None
         assert pol.spec_for("logits", (2, 8, 512)) is not None
 
     def test_constrain_inside_jit(self):
         from repro.distrib.actsharding import ActivationPolicy, use_policy, constrain
+        from repro.launch.mesh import make_mesh
 
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with use_policy(ActivationPolicy(mesh=mesh)):
             out = jax.jit(lambda x: constrain(x, "tokens") * 2)(
                 jnp.ones((2, 4, 8))

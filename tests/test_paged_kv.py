@@ -297,6 +297,43 @@ class TestPagedDecodeFidelity:
             np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
             tok = jnp.argmax(la[:, -1, :], axis=-1).astype(jnp.int32)[:, None]
 
+    def test_interpret_kernel_decode_matches_gather(self, fid_setup):
+        """kv_kernel="interpret" runs the Pallas paged kernel, interpreted,
+        inside the model: same logits as the gather path to f32 rounding
+        (the kernel's online softmax sums in another order)."""
+        cfg, model, params = fid_setup
+        kcfg = cfg.with_(kv_kernel="interpret")
+        B, T, max_len = self.B, 4, self.MAX_LEN
+        ca = _identity_paged_cache(model, cfg, B, max_len, self.PS)
+        cb = _identity_paged_cache(model, kcfg, B, max_len, self.PS)
+        toks = np.stack([_tokens(T, seed=12, vocab=cfg.vocab),
+                         _tokens(T, seed=13, vocab=cfg.vocab)])
+        mask = jnp.ones((B,), bool)
+        for t in range(T):
+            tok = jnp.asarray(toks[:, t:t + 1])
+            pos = jnp.full((B,), t, jnp.int32)
+            la, ca = model.paged_decode_step(params, ca, tok, pos, cfg,
+                                             slot_mask=mask)
+            lb, cb = model.paged_decode_step(params, cb, tok, pos, kcfg,
+                                             slot_mask=mask)
+            np.testing.assert_allclose(
+                np.asarray(la, np.float32), np.asarray(lb, np.float32),
+                atol=5e-2, rtol=5e-2,
+            )
+
+    def test_pallas_kernel_never_interprets_on_its_own(self, fid_setup):
+        """kv_kernel="pallas" is the compiled TPU kernel: off the chip it
+        raises instead of quietly running interpreted."""
+        cfg, model, params = fid_setup
+        kcfg = cfg.with_(kv_kernel="pallas")
+        cache = _identity_paged_cache(model, kcfg, self.B, self.MAX_LEN,
+                                      self.PS)
+        tok = jnp.zeros((self.B, 1), jnp.int32)
+        pos = jnp.zeros((self.B,), jnp.int32)
+        with pytest.raises(ValueError, match="interpret"):
+            model.paged_decode_step(params, cache, tok, pos, kcfg,
+                                    slot_mask=jnp.ones((self.B,), bool))
+
     def test_masked_rows_leave_pages_untouched(self, fid_setup):
         """slot_mask=False rows write nothing: their writes land on the
         trash page, so every real page survives bitwise."""
@@ -329,8 +366,8 @@ class TestPagedDecodeFidelity:
             pt[b] = 1 + b * MP + np.arange(MP)
         pt_dev = jnp.asarray(pt)
         store = {
-            "k_pages": jnp.zeros((1 + B * MP, ps, KVH, D), jnp.float32),
-            "v_pages": jnp.zeros((1 + B * MP, ps, KVH, D), jnp.float32),
+            "k_pages": jnp.zeros((1 + B * MP, KVH, ps, D), jnp.float32),
+            "v_pages": jnp.zeros((1 + B * MP, KVH, ps, D), jnp.float32),
         }
         rng = np.random.default_rng(11)
         mask = jnp.ones((B,), bool)
@@ -425,8 +462,8 @@ class TestPagedAttentionKernel:
         rng = np.random.default_rng(seed)
         NP = 1 + B * MP
         q = jnp.asarray(rng.standard_normal((B, H, D)), dtype)
-        k = jnp.asarray(rng.standard_normal((NP, ps, KVH, D)), dtype)
-        v = jnp.asarray(rng.standard_normal((NP, ps, KVH, D)), dtype)
+        k = jnp.asarray(rng.standard_normal((NP, KVH, ps, D)), dtype)
+        v = jnp.asarray(rng.standard_normal((NP, KVH, ps, D)), dtype)
         pt = np.zeros((B, MP), np.int32)
         for b in range(B):
             pt[b] = 1 + b * MP + rng.permutation(MP)  # non-contiguous!
@@ -463,7 +500,7 @@ class TestPagedAttentionKernel:
         rng = np.random.default_rng(6)
         B, KVH, D, ps, MP = 2, 2, 4, 4, 3
         NP = 1 + B * MP
-        pages = jnp.asarray(rng.standard_normal((NP, ps, KVH, D)),
+        pages = jnp.asarray(rng.standard_normal((NP, KVH, ps, D)),
                             jnp.float32)
         pt = np.zeros((B, MP), np.int32)
         for b in range(B):
@@ -472,18 +509,19 @@ class TestPagedAttentionKernel:
         assert view.shape == (B, KVH, MP * ps, D)
         flat = np.asarray(pages)
         for b in range(B):
-            expect = flat[pt[b]].reshape(MP * ps, KVH, D)
-            np.testing.assert_array_equal(
-                view[b], expect.transpose(1, 0, 2)
-            )
+            # token t of row b sits in page pt[b, t // ps] at t % ps
+            for t in range(MP * ps):
+                np.testing.assert_array_equal(
+                    view[b, :, t], flat[pt[b, t // ps], :, t % ps]
+                )
 
     def test_fully_masked_row_yields_zeros_not_nan(self):
         """pos = -1 keeps every key masked; the kernel's l==0 guard must
         return zeros instead of 0/0 NaNs."""
         B, H, KVH, D, ps, MP = 1, 2, 2, 8, 4, 2
         q = jnp.ones((B, H, D), jnp.float32)
-        k = jnp.ones((1 + MP, ps, KVH, D), jnp.float32)
-        v = jnp.ones((1 + MP, ps, KVH, D), jnp.float32)
+        k = jnp.ones((1 + MP, KVH, ps, D), jnp.float32)
+        v = jnp.ones((1 + MP, KVH, ps, D), jnp.float32)
         pt = jnp.asarray([[1, 2]], jnp.int32)
         pos = jnp.asarray([-1], jnp.int32)
         out = paged_attention(q, k, v, pt, pos, interpret=True)
