@@ -1,0 +1,3 @@
+"""forge.dispatches_per_tick.batch: decode-front segments dispatched plus host
+ops replayed, per decode call."""
+from bench.readers import dispatches_per_tick as read  # noqa: F401
