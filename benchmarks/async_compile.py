@@ -11,11 +11,6 @@ Both servers warm ONLY the top decode rung, then serve the same
 retire-heavy workload whose occupancy decays through the cold lower
 rungs.  Reported / gated:
 
-* tick latency — p50/p99/max ms per scheduler tick for inline vs
-  async.  Reported, not gated: on this CPU container the background
-  workers contend for the GIL during the pure-Python phases, which
-  inflates async tick wall time at smoke scale; the mechanism gates
-  below are the deterministic signal.
 * ``warm_fallbacks`` (async) — ticks served by a padded dominating
   rung while the exact rung compiled in the background (gated >= 1),
 * ``compile_wait_s`` split — request-visible stall seconds.  The async
@@ -134,18 +129,12 @@ def run(csv: Csv) -> None:
             "async_compile/inline",
             rs["wall_s"] * 1e6,
             f"tok_per_s={rs['tok_per_s']:.0f};"
-            f"tick_ms_p50={rs['tick_ms_p50']:.2f};"
-            f"tick_ms_p99={rs['tick_ms_p99']:.2f};"
-            f"tick_ms_max={rs['tick_ms_max']:.2f};"
             f"compile_wait_s={sync_wait:.3f}",
         )
         csv.row(
             "async_compile/async",
             ra["wall_s"] * 1e6,
             f"tok_per_s={ra['tok_per_s']:.0f};"
-            f"tick_ms_p50={ra['tick_ms_p50']:.2f};"
-            f"tick_ms_p99={ra['tick_ms_p99']:.2f};"
-            f"tick_ms_max={ra['tick_ms_max']:.2f};"
             f"warm_fallbacks={ra['warm_fallbacks']};"
             f"fallback_calls={bs.fallback_calls};"
             f"fallback_cells_padded={bs.fallback_cells_padded};"

@@ -296,7 +296,7 @@ def serve_phase(rng, dev) -> bool:
         f"compiles_post_warmup={res['compiles']} swaps={res['swaps']}")
     log(f"[serve] bring-up timings, one run, not a benchmark: "
         f"ttft_p50_s={res['ttft_p50_s']!r} ttft_p99_s={res['ttft_p99_s']!r} "
-        f"tok_per_s={res['tok_per_s']!r} tick_ms_p50={res['tick_ms_p50']!r}")
+        f"tok_per_s={res['tok_per_s']!r}")
 
     # fidelity (a): the served prompts through the Forge prefill program
     # against the plain jit prefill, same bf16 weights

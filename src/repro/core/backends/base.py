@@ -15,11 +15,15 @@ from ``PipelineConfig.backend`` / ``forge_compile(..., backend=...)``.
 """
 from __future__ import annotations
 
+import threading
+import time
 from abc import ABC, abstractmethod
+from contextlib import contextmanager
 from typing import (
     Any,
     Callable,
     Dict,
+    Iterator,
     List,
     Optional,
     Protocol,
@@ -27,7 +31,31 @@ from typing import (
     runtime_checkable,
 )
 
+from repro.runtime.trace import span
+
 from ..lowering import RGIRProgram
+
+#: per-thread running total of seconds spent in XLA's compile of backend
+#: programs; a compile reads it before and after (compile-service workers
+#: build on their own threads)
+_xla_clock = threading.local()
+
+
+def xla_compile_seconds() -> float:
+    """Seconds this thread has spent in :func:`xla_compiling` so far."""
+    return getattr(_xla_clock, "total", 0.0)
+
+
+@contextmanager
+def xla_compiling() -> Iterator[None]:
+    """Time one XLA compile of a backend program, in an ``xla.compile``
+    span."""
+    t0 = time.perf_counter()
+    try:
+        with span("xla.compile"):
+            yield
+    finally:
+        _xla_clock.total = xla_compile_seconds() + time.perf_counter() - t0
 
 
 @runtime_checkable

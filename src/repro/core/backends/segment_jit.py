@@ -36,6 +36,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
 import jax
 
 from repro.runtime import chaos
+from repro.runtime.trace import span
 
 from .._jax_internal import trace_state_clean
 from ..bufalloc import allocate, segment_donations
@@ -48,7 +49,7 @@ from ..executor import (
     analyzed_from_persisted,
 )
 from ..lowering import RGIROp, RGIRProgram
-from .base import Backend, register_backend
+from .base import Backend, register_backend, xla_compiling
 
 
 def _spec(aval: Any) -> jax.ShapeDtypeStruct:
@@ -272,7 +273,8 @@ class SegmentExecutor(BufferFilePoolMixin, PaddedExecutionMixin):
         # precompiled dispatch plan: the per-call loop touches only these
         # tuples (fns + slot indices) — no reg->slot lookups, no dict
         self._plans = tuple(
-            (s.fn, s.fn_nodonate, s.in_slots, s.free_slots, s.out_slots)
+            (s.fn, s.fn_nodonate, s.in_slots, s.free_slots, s.out_slots,
+             s.index, s.device)
             for s in self.segments
         )
         self._n_donated_args = sum(
@@ -302,9 +304,10 @@ class SegmentExecutor(BufferFilePoolMixin, PaddedExecutionMixin):
         if warmup:
             for seg in self.segments:
                 if seg.compiled:
-                    seg.fn.lower(
-                        *(_spec(reg_avals[r]) for r in seg.live_in)
-                    ).compile()
+                    with xla_compiling():
+                        seg.fn.lower(
+                            *(_spec(reg_avals[r]) for r in seg.live_in)
+                        ).compile()
 
         self.stats = ExecutorStats(
             n_instructions=n,
@@ -351,21 +354,23 @@ class SegmentExecutor(BufferFilePoolMixin, PaddedExecutionMixin):
             for b, v in zip(self._input_bufs, flat_inputs):
                 file[b] = v
             executed = 0
-            for fn, fn_plain, in_slots, free_slots, out_slots in self._plans:
-                # chaos: fires BEFORE the segment runs, so no donation has
-                # consumed this call's buffers yet; program inputs are
-                # never donated, so the caller may retry the whole call
-                chaos.maybe_fault(chaos.SITE_DISPATCH)
-                f = fn if donate_ok else fn_plain
-                out_vals = f(*[file[b] for b in in_slots])
-                executed += 1
-                # clear BEFORE the stores: a register dying inside this
-                # segment may share its slot with a live-out born later
-                # in it (and its buffer may just have been donated)
-                for b in free_slots:
-                    file[b] = None
-                for b, v in zip(out_slots, out_vals):
-                    file[b] = v
+            for (fn, fn_plain, in_slots, free_slots, out_slots, index,
+                 device) in self._plans:
+                with span("forge.segment", index=index, device=device):
+                    # chaos: fires BEFORE the segment runs, so no donation
+                    # has consumed this call's buffers yet; program inputs
+                    # are never donated, so the caller may retry the call
+                    chaos.maybe_fault(chaos.SITE_DISPATCH)
+                    f = fn if donate_ok else fn_plain
+                    out_vals = f(*[file[b] for b in in_slots])
+                    executed += 1
+                    # clear BEFORE the stores: a register dying inside this
+                    # segment may share its slot with a live-out born later
+                    # in it (and its buffer may just have been donated)
+                    for b in free_slots:
+                        file[b] = None
+                    for b, v in zip(out_slots, out_vals):
+                        file[b] = v
             outs = [file[b] for b in self._output_bufs]
         finally:
             self._release_file(file)

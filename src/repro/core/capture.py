@@ -108,6 +108,10 @@ def _keep_opaque(eqn) -> bool:
     return name.startswith(FORGE_MARKER)
 
 
+def _join_scope(outer: str, inner: str) -> str:
+    return f"{outer}/{inner}" if outer and inner else outer or inner
+
+
 def from_closed_jaxpr(closed: ClosedJaxpr, *, inline: bool = True) -> Graph:
     """Build a Graph from a ClosedJaxpr, inlining wrapper equations."""
     g = Graph()
@@ -126,9 +130,10 @@ def from_closed_jaxpr(closed: ClosedJaxpr, *, inline: bool = True) -> Graph:
     for cv, cval in zip(closed.jaxpr.constvars, closed.consts):
         write(cv, g.add_const(cval, getattr(cv, "aval", None)))
 
-    def process(jaxpr, depth: int) -> None:
+    def process(jaxpr, depth: int, prefix: str) -> None:
         for eqn in jaxpr.eqns:
             pname = eqn.primitive.name
+            scope = _join_scope(prefix, str(eqn.source_info.name_stack))
             sub = _sub_jaxpr(eqn) if (inline and pname in _INLINE_PRIMS) else None
             if sub is not None and not _keep_opaque(eqn) and depth < 32:
                 # inline: bind sub invars to our operands, consts to consts
@@ -140,14 +145,16 @@ def from_closed_jaxpr(closed: ClosedJaxpr, *, inline: bool = True) -> Graph:
                         inner_env[scv] = g.add_const(sval, getattr(scv, "aval", None))
                     saved = {k: env.get(k) for k in inner_env}
                     env.update(inner_env)
-                    process(sub.jaxpr, depth + 1)
+                    process(sub.jaxpr, depth + 1, scope)
                     for ov, sv in zip(eqn.outvars, sub.jaxpr.outvars):
                         write(ov, read(sv))
                     # NOTE: no env cleanup needed — jaxpr vars are unique objects
                     continue
             # opaque node
             op = pname
-            meta = {}
+            # the named scope the equation was traced under; lowering
+            # re-enters it so the replayed ops keep their op_name
+            meta = {"scope": scope} if scope else {}
             if sub is not None and _keep_opaque(eqn):
                 op = "forge." + str(eqn.params.get("name"))[len(FORGE_MARKER):]
                 meta["call_jaxpr"] = sub
@@ -162,7 +169,7 @@ def from_closed_jaxpr(closed: ClosedJaxpr, *, inline: bool = True) -> Graph:
             for ov, gv in zip(eqn.outvars, node.outvars):
                 write(ov, gv)
 
-    process(closed.jaxpr, 0)
+    process(closed.jaxpr, 0, "")
     g.outvars = [read(v) for v in closed.jaxpr.outvars]
     g.validate()
     return g

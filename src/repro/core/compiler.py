@@ -24,7 +24,10 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 import jax
 import numpy as np
 
+from repro.runtime.trace import span
+
 from .backends import ExecutorLike, get_backend
+from .backends.base import xla_compile_seconds
 from .cache import (
     CompileCache,
     UncacheableProgram,
@@ -68,6 +71,10 @@ class CompilationResult:
     optimize_ms: float = 0.0
     lower_ms: float = 0.0
     backend_ms: float = 0.0  # schedule + alloc + codegen (or cache lookup)
+    #: XLA's compile of backend programs anywhere in this compile (nested
+    #: block-body compiles inside capture included): the part of the four
+    #: phases that is not Forge's own
+    xla_ms: float = 0.0
     total_ms: float = 0.0
     # Phase-4 statistics
     executor_stats: Optional[ExecutorStats] = None
@@ -85,6 +92,12 @@ class CompilationResult:
     cache_misses: int = 0
     #: canonical bucket ShapeKey string for bucketed compiles (None = exact)
     shape_key: Optional[str] = None
+
+    @property
+    def forge_phases_ms(self) -> float:
+        """Phases 1-4 without XLA's compile."""
+        return (self.capture_ms + self.optimize_ms + self.lower_ms
+                + self.backend_ms - self.xla_ms)
 
     @property
     def node_reduction(self) -> float:
@@ -461,6 +474,8 @@ class BucketedModule:
             self.stats.note_lookup(
                 hit=False,
                 compile_s=time.perf_counter() - t0,
+                forge_phases_s=mod.result.forge_phases_ms / 1e3,
+                xla_compile_s=mod.result.xla_ms / 1e3,
                 background=background,
             )
             if not background:
@@ -768,26 +783,73 @@ class ForgeCompiler:
         shorthand.
         """
         t_total = time.perf_counter()
+        xla0 = xla_compile_seconds()
 
         # Phase 1 — capture
-        cap = trace_to_graph(
-            fn, *example_args, poly_axes=poly_axes, poly_axes_nd=poly_axes_nd
-        )
+        with span("forge.capture"):
+            cap = trace_to_graph(
+                fn, *example_args, poly_axes=poly_axes,
+                poly_axes_nd=poly_axes_nd,
+            )
         g = cap.graph
         nodes_before = g.num_nodes()
 
         # Phase 2 — optimization passes
         t0 = time.perf_counter()
-        records = run_forge_passes(g, cfg=self.config)
+        with span("forge.optimize"):
+            records = run_forge_passes(g, cfg=self.config)
         optimize_ms = (time.perf_counter() - t0) * 1e3
 
         # Phase 3 — lowering
         t0 = time.perf_counter()
-        prog = lower_to_rgir(g)
+        with span("forge.lower"):
+            prog = lower_to_rgir(g)
         lower_ms = (time.perf_counter() - t0) * 1e3
 
         # Phase 4 — backend codegen (compile-cache hit: a dictionary read)
         t0 = time.perf_counter()
+        with span("forge.backend"):
+            executor, cache_key, cache_hit, disk_hit = self._build(
+                prog, shape_key
+            )
+        backend_ms = (time.perf_counter() - t0) * 1e3
+
+        cost = score_graph(g, self.config.precision)
+        result = CompilationResult(
+            nodes_before=nodes_before,
+            nodes_after=g.num_nodes(),
+            fused_ops=cost.n_fused,
+            attention_fused=cost.n_attn_fused,
+            pass_records=records,
+            capture_ms=cap.capture_ms,
+            optimize_ms=optimize_ms,
+            lower_ms=lower_ms,
+            backend_ms=backend_ms,
+            xla_ms=(xla_compile_seconds() - xla0) * 1e3,
+            total_ms=(time.perf_counter() - t_total) * 1e3,
+            # on a hit the executor is shared: report its analysis stats
+            # but not the run counters other modules accumulated on it
+            executor_stats=(
+                executor.stats.fresh_snapshot() if cache_hit
+                else executor.stats
+            ),
+            cost=cost,
+            tied_weights=len(cap.tied_map),
+            config=self.config,
+            backend=self.backend_name,
+            cache_hit=cache_hit,
+            cache_disk_hit=disk_hit,
+            cache_key=cache_key,
+            cache_hits=self.cache.stats.hits if self.cache else 0,
+            cache_misses=self.cache.stats.misses if self.cache else 0,
+            shape_key=str(shape_key) if shape_key is not None else None,
+        )
+        return CompiledModule(executor, cap, result, g)
+
+    def _build(self, prog, shape_key: Optional[ShapeKey]):
+        """Phase 4: the backend's executor for ``prog``, through the
+        compile cache.  Returns (executor, cache_key, cache_hit,
+        disk_hit)."""
         backend = get_backend(self.backend_name)
         cache_key: Optional[str] = None
         executor = None
@@ -835,38 +897,7 @@ class ForgeCompiler:
                     except Exception:
                         disk_entry = None
                 self.cache.put(cache_key, executor, disk_entry=disk_entry)
-        backend_ms = (time.perf_counter() - t0) * 1e3
-
-        cost = score_graph(g, self.config.precision)
-        result = CompilationResult(
-            nodes_before=nodes_before,
-            nodes_after=g.num_nodes(),
-            fused_ops=cost.n_fused,
-            attention_fused=cost.n_attn_fused,
-            pass_records=records,
-            capture_ms=cap.capture_ms,
-            optimize_ms=optimize_ms,
-            lower_ms=lower_ms,
-            backend_ms=backend_ms,
-            total_ms=(time.perf_counter() - t_total) * 1e3,
-            # on a hit the executor is shared: report its analysis stats
-            # but not the run counters other modules accumulated on it
-            executor_stats=(
-                executor.stats.fresh_snapshot() if cache_hit
-                else executor.stats
-            ),
-            cost=cost,
-            tied_weights=len(cap.tied_map),
-            config=self.config,
-            backend=self.backend_name,
-            cache_hit=cache_hit,
-            cache_disk_hit=disk_hit,
-            cache_key=cache_key,
-            cache_hits=self.cache.stats.hits if self.cache else 0,
-            cache_misses=self.cache.stats.misses if self.cache else 0,
-            shape_key=str(shape_key) if shape_key is not None else None,
-        )
-        return CompiledModule(executor, cap, result, g)
+        return executor, cache_key, cache_hit, disk_hit
 
     def compile_bucketed(
         self,

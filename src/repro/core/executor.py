@@ -93,12 +93,6 @@ class ExecutorStats:
     #: calls that had to materialize a fresh buffer file (first call /
     #: concurrent overlap); steady-state replay keeps this flat
     file_pool_misses: int = 0
-    # -- paged-KV pool counters (serve scheduler fills these on the decode
-    #    front's aggregate stats; zero for non-paged runs) ----------------
-    kv_pages_in_use: int = 0
-    kv_peak_pages_in_use: int = 0
-    kv_prefix_hits: int = 0
-    kv_tokens_reused: int = 0
 
     def __post_init__(self) -> None:
         # per-call counters are folded in under a lock so a shared stats

@@ -266,6 +266,9 @@ class Graph:
         rebuilding the insertion-ordered dict once (O(n), passes call it
         rarely).
         """
+        meta = dict(meta or {})
+        if "scope" in anchor.meta:  # the fused node keeps the chain's scope
+            meta.setdefault("scope", anchor.meta["scope"])
         node = self.add_node(op, None, params, invars, out_avals, meta)
         order: Dict[int, GNode] = {}
         for nid, n in self.nodes.items():

@@ -24,9 +24,10 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
-from ._jax_internal import Primitive
+from ._jax_internal import Primitive, trace_state_clean
 from .graph import Graph, GLit, GNode, GVar, Operand
 from .fused_ops import fused_callable
 
@@ -113,6 +114,21 @@ class RGIRProgram:
             constants=self.constants,
             reg_avals=self.reg_avals,
         )
+
+
+def _scoped(target: Callable, scope: str) -> Callable:
+    """``target`` inside the named scope its equation was traced under,
+    whenever it runs under a trace, so the ops it binds keep their
+    ``op_name``.  An eager call compiles a program of its own, where the
+    scope would name nothing, and skips it."""
+
+    def call(*vals):
+        if trace_state_clean():
+            return target(*vals)
+        with jax.named_scope(scope):
+            return target(*vals)
+
+    return call
 
 
 def _node_flops(node: GNode) -> float:
@@ -206,6 +222,8 @@ def lower_to_rgir(g: Graph) -> RGIRProgram:
 
             target = make_target()
             opcode = f"{route_device(node.op)}.{node.op}"
+        if node.meta.get("scope"):
+            target = _scoped(target, node.meta["scope"])
 
         ops.append(
             RGIROp(

@@ -295,8 +295,9 @@ def check_ragged_decode_fidelity(
     )
 
 
-def bucket_report(stats: Any) -> str:
-    """One-line summary of a BucketedModule's BucketStats."""
+def bucket_report(stats: Any, page_pool: Any = None) -> str:
+    """One-line summary of a BucketedModule's BucketStats, with the page
+    pool's counters (``PagePool`` and its ``PageStats``) when given."""
     per = ", ".join(
         f"{k}:{v}" for k, v in sorted(stats.per_bucket_calls.items())
     )
@@ -320,12 +321,13 @@ def bucket_report(stats: Any) -> str:
             f" (+{stats.fallback_cells_padded} padded cells)"
         )
     pages = ""
-    if getattr(stats, "kv_pages_capacity", 0):
+    if page_pool is not None:
+        ps = page_pool.stats
         pages = (
-            f" kv_pages={stats.kv_pages_in_use}/{stats.kv_pages_capacity}"
-            f" (peak={stats.kv_peak_pages_in_use},"
-            f" prefix_hits={stats.kv_prefix_hits},"
-            f" tokens_reused={stats.kv_tokens_reused})"
+            f" kv_pages={page_pool.pages_in_use}/{page_pool.capacity}"
+            f" (peak={ps.peak_pages_in_use},"
+            f" prefix_hits={ps.prefix_hits},"
+            f" tokens_reused={ps.tokens_reused})"
         )
     faults = ""
     if (getattr(stats, "faults_injected", 0)
@@ -342,6 +344,7 @@ def bucket_report(stats: Any) -> str:
         f"buckets: compiles={stats.compiles} hits={stats.bucket_hits} "
         f"(hit_rate={stats.hit_rate:.1%}) calls={stats.calls} "
         f"pad_waste={stats.pad_waste:.1%} compile_s={stats.compile_s:.2f}"
+        f" (forge={stats.forge_phases_s:.2f} xla={stats.xla_compile_s:.2f})"
         f"{async_note}{evic}{pool}{pages}{faults} [{per}]"
     )
 

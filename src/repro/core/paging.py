@@ -53,7 +53,7 @@ TRASH_PAGE = 0
 @dataclass
 class PageStats:
     """Page-pool / prefix-tree counters (surfaced via bucket_report and
-    the serve CLI; see also ``ExecutorStats`` page fields)."""
+    the serve CLI)."""
 
     #: pages handed out by :meth:`PagePool.alloc` (fresh allocations)
     pages_allocated: int = 0

@@ -562,7 +562,13 @@ class BucketStats:
     calls: int = 0
     bucket_hits: int = 0
     compiles: int = 0
+    #: wall seconds of every bucket compile (padding the example args,
+    #: Forge Phases 1-4, XLA's compile of the segment programs)
     compile_s: float = 0.0
+    #: the Forge Phases 1-4 part of ``compile_s``, XLA's compile left out
+    forge_phases_s: float = 0.0
+    #: XLA's compile of the accel segment programs, inside Phase 4
+    xla_compile_s: float = 0.0
     #: request-visible compile stall: seconds a *dispatching* caller
     #: spent blocked on a cold-bucket build (inline compile, build-lock
     #: convoy, or an async future it had to wait out).  Disjoint from
@@ -594,17 +600,6 @@ class BucketStats:
     pool_misses: int = 0
     #: device bytes served from the pool instead of freshly allocated
     pool_bytes_reused: int = 0
-    # -- paged-KV pool counters (filled by the paged serve scheduler) ------
-    #: KV pages currently referenced (PagePool.pages_in_use snapshot)
-    kv_pages_in_use: int = 0
-    #: page-pool capacity (allocatable pages; excludes the trash page)
-    kv_pages_capacity: int = 0
-    #: high-water mark of pages in use across the run
-    kv_peak_pages_in_use: int = 0
-    #: prefix-tree lookups that matched at least one full page
-    kv_prefix_hits: int = 0
-    #: prompt tokens whose prefill was skipped via shared-prefix pages
-    kv_tokens_reused: int = 0
     # -- fault-tolerance counters (runtime.chaos + the serve scheduler) ----
     #: faults the installed FaultPlan fired across all sites
     faults_injected: int = 0
@@ -646,6 +641,8 @@ class BucketStats:
         *,
         hit: bool,
         compile_s: float = 0.0,
+        forge_phases_s: float = 0.0,
+        xla_compile_s: float = 0.0,
         background: bool = False,
     ) -> None:
         with self._lock:
@@ -654,6 +651,8 @@ class BucketStats:
             else:
                 self.compiles += 1
                 self.compile_s += compile_s
+                self.forge_phases_s += forge_phases_s
+                self.xla_compile_s += xla_compile_s
                 if background:
                     self.compile_background_s += compile_s
 

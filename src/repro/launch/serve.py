@@ -58,6 +58,7 @@ from ..core.shapekey import LadderPolicy, propose_rungs
 from ..models import get_model
 from ..runtime import chaos
 from ..runtime.chaos import RequestError, SystemError_
+from ..runtime.trace import gc_spans, span
 from .steps import (
     POISON_TOKEN,
     blend_cache_rows,
@@ -1019,6 +1020,9 @@ class _Slot:
     # -- SLO bookkeeping ---------------------------------------------------
     #: wall clock at which the request arrived (TTFT/latency origin)
     arrival_wall: float = 0.0
+    #: wall clock at the start of the admission that granted the slot
+    #: (queue wait = admit_wall - arrival_wall)
+    admit_wall: float = 0.0
     #: wall clock of the first emitted token (None until it exists)
     first_wall: Optional[float] = None
     #: times this slot was preempted (pages parked) and later resumed
@@ -1374,6 +1378,12 @@ class SlotScheduler:
     # -- the scheduling loop ----------------------------------------------
 
     def run(self, requests: Sequence[Request]) -> Dict[str, Any]:
+        """:meth:`_run`, with a ``py.gc`` span around each full garbage
+        collection while it serves."""
+        with gc_spans():
+            return self._run(requests)
+
+    def _run(self, requests: Sequence[Request]) -> Dict[str, Any]:
         """Serve ``requests`` to completion; returns results + metrics.
 
         The clock is the decode-dispatch counter (``tick``):
@@ -1459,10 +1469,6 @@ class SlotScheduler:
         #: token columns not yet copied to host (steady-state ticks defer
         #: the D2H sync; harvested at the next boundary — see _harvest)
         pending: List[Any] = []
-        #: per-tick host wall seconds (admission + resize + dispatch);
-        #: inline compile stalls at rung crossings land here, which is
-        #: what the async-vs-inline p99 comparison measures
-        tick_s: List[float] = []
         t0 = time.perf_counter()
 
         def active_count() -> int:
@@ -1515,6 +1521,7 @@ class SlotScheduler:
                 "priority": s.req.priority,
                 "ttft_s": (s.first_wall - s.arrival_wall
                            if s.first_wall is not None else None),
+                "queue_wait_s": s.admit_wall - s.arrival_wall,
                 "latency_s": now - s.arrival_wall,
             }
             if error is not None:
@@ -1541,6 +1548,12 @@ class SlotScheduler:
                                "(quarantined)")
 
         def harvest() -> None:
+            """:func:`collect` inside a ``serve.harvest`` span."""
+            if pending:
+                with span("serve.harvest"):
+                    collect()
+
+        def collect() -> None:
             """Copy the deferred token columns to host, in tick order.
 
             The active set cannot have changed while ticks were pending
@@ -1666,6 +1679,7 @@ class SlotScheduler:
                     "priority": s.req.priority,
                     "ttft_s": (s.first_wall - s.arrival_wall
                                if s.first_wall is not None else None),
+                    "queue_wait_s": s.admit_wall - s.arrival_wall,
                     "latency_s": time.perf_counter() - s.arrival_wall,
                     "error": why,
                     "error_type": "SystemError",
@@ -1776,45 +1790,46 @@ class SlotScheduler:
                     # cur_tok back in)
                     harvest()
                 if target != extent:
-                    keep = [(i, s) for i, s in enumerate(slots)
-                            if s is not None]
-                    if paged:
-                        # O(table) resize: surviving rows' page-table
-                        # entries move; the KV pages themselves do not
-                        new_pt = np.full((target, MP), TRASH_PAGE,
-                                         np.int32)
-                        for dst, (i, _) in enumerate(keep):
-                            new_pt[dst] = pt_host[i]
-                        pt_host = new_pt
-                        if extent > 0:
-                            self.metrics["resizes"] += 1
-                    else:
-                        new_cache = srv._acquire_cache(target)
-                        if keep and cache is not None:
-                            new_cache = self._gather_rows(
-                                cache, new_cache, [i for i, _ in keep]
-                            )
-                        if cache is not None:
-                            srv._release_cache(extent, cache)
-                            self.metrics["resizes"] += 1
-                        cache = new_cache
-                    new_tok = np.zeros((target, 1), np.int32)
-                    new_pos = np.zeros((target,), np.int32)
-                    new_slots: List[Optional[_Slot]] = [None] * target
-                    for dst, (i, s) in enumerate(keep):
-                        new_slots[dst] = s
-                        new_tok[dst] = cur_tok[i]
-                        new_pos[dst] = cur_pos[i]
-                    slots, cur_tok, cur_pos = new_slots, new_tok, new_pos
-                    extent = target
-                    dev_args = None
-                    if paged:
-                        pt_dev = jnp.asarray(pt_host)
-                    # on a resolve failure (injected build fault, poisoned
-                    # key) mod stays None and the dispatch path retries
-                    # the resolve next tick — never dispatches stale
-                    mod = None
-                    resolve_program()
+                    with span("serve.resize"):
+                        keep = [(i, s) for i, s in enumerate(slots)
+                                if s is not None]
+                        if paged:
+                            # O(table) resize: surviving rows' page-table
+                            # entries move; the KV pages themselves do not
+                            new_pt = np.full((target, MP), TRASH_PAGE,
+                                             np.int32)
+                            for dst, (i, _) in enumerate(keep):
+                                new_pt[dst] = pt_host[i]
+                            pt_host = new_pt
+                            if extent > 0:
+                                self.metrics["resizes"] += 1
+                        else:
+                            new_cache = srv._acquire_cache(target)
+                            if keep and cache is not None:
+                                new_cache = self._gather_rows(
+                                    cache, new_cache, [i for i, _ in keep]
+                                )
+                            if cache is not None:
+                                srv._release_cache(extent, cache)
+                                self.metrics["resizes"] += 1
+                            cache = new_cache
+                        new_tok = np.zeros((target, 1), np.int32)
+                        new_pos = np.zeros((target,), np.int32)
+                        new_slots: List[Optional[_Slot]] = [None] * target
+                        for dst, (i, s) in enumerate(keep):
+                            new_slots[dst] = s
+                            new_tok[dst] = cur_tok[i]
+                            new_pos[dst] = cur_pos[i]
+                        slots, cur_tok, cur_pos = new_slots, new_tok, new_pos
+                        extent = target
+                        dev_args = None
+                        if paged:
+                            pt_dev = jnp.asarray(pt_host)
+                        # on a resolve failure (injected build fault, poisoned
+                        # key) mod stays None and the dispatch path retries
+                        # the resolve next tick — never dispatches stale
+                        mod = None
+                        resolve_program()
                 # pack queued requests AND parked resumes into every
                 # free slot (13+3 → B16).  Resumes and fresh admissions
                 # compete in one EDF order (a parked slot keeps its
@@ -1853,26 +1868,32 @@ class SlotScheduler:
                 queue.clear()
                 queue.extend(r for kind, r in cand if kind == "new")
                 if admitted:
-                    if paged:
-                        cache = self._admit_paged(admitted, slots, cache,
-                                                  extent, cur_tok, cur_pos,
-                                                  pt_host, queue)
-                        pt_dev = jnp.asarray(pt_host)
-                    else:
-                        cache = self._admit(admitted, slots, cache, extent,
-                                            cur_tok, cur_pos)
-                    dev_args = None
-                    # degenerate 1-token budgets finish at admission
-                    # (a paged deferral leaves slots[i] None — skip it);
-                    # a poisoned first token quarantines the row instead
+                    t_admit = time.perf_counter()
                     for i in admitted:
-                        s = slots[i]
-                        if s is None:
-                            continue
-                        if s.poisoned:
-                            quarantine(i, s)
-                        elif s.fill is None and s.remaining <= 0:
-                            retire(i, s)
+                        slots[i].admit_wall = t_admit
+                    # ";": the profiler splits span arguments at ","
+                    rids = ";".join(str(slots[i].req.rid) for i in admitted)
+                    with span("serve.admit", rids=rids):
+                        if paged:
+                            cache = self._admit_paged(admitted, slots, cache,
+                                                      extent, cur_tok, cur_pos,
+                                                      pt_host, queue)
+                            pt_dev = jnp.asarray(pt_host)
+                        else:
+                            cache = self._admit(admitted, slots, cache, extent,
+                                                cur_tok, cur_pos)
+                        dev_args = None
+                        # degenerate 1-token budgets finish at admission
+                        # (a paged deferral leaves slots[i] None — skip it);
+                        # a poisoned first token quarantines the row instead
+                        for i in admitted:
+                            s = slots[i]
+                            if s is None:
+                                continue
+                            if s.poisoned:
+                                quarantine(i, s)
+                            elif s.fill is None and s.remaining <= 0:
+                                retire(i, s)
 
             if not any(s is not None for s in slots):
                 if pendreq:
@@ -1884,7 +1905,8 @@ class SlotScheduler:
                         wait = (t0 + (pendreq[0].arrival_s or 0.0)
                                 - time.perf_counter())
                         if wait > 0:
-                            time.sleep(min(wait, 0.025))
+                            with span("serve.wait_arrival"):
+                                time.sleep(min(wait, 0.025))
                         tick += 1
                     else:
                         tick = max(tick + 1, pendreq[0].arrival)
@@ -1925,19 +1947,25 @@ class SlotScheduler:
             # donated) and the executor releases its pooled scratch in a
             # finally, so re-dispatching the same tick after a transient
             # failure is state-safe
+            n_act = sum(s is not None for s in slots)
             attempt = 0
             while True:
                 try:
                     if paged:
-                        out_tok, cache = mod(params, cache, pt_dev,
-                                             tok_dev, pos_dev, mask_dev)
+                        with span("serve.dispatch", extent=extent,
+                                  n_active=n_act):
+                            out_tok, cache = mod(params, cache, pt_dev,
+                                                 tok_dev, pos_dev, mask_dev)
                         # pool invariant holds after every tick: every
                         # page is either referenced or on the free list,
                         # never both
-                        pool.check()
+                        with span("serve.pool_check"):
+                            pool.check()
                     else:
-                        out_tok, cache = mod(params, cache, tok_dev,
-                                             pos_dev, mask_dev)
+                        with span("serve.dispatch", extent=extent,
+                                  n_active=n_act):
+                            out_tok, cache = mod(params, cache, tok_dev,
+                                                 pos_dev, mask_dev)
                     break
                 except Exception:
                     attempt += 1
@@ -1957,7 +1985,6 @@ class SlotScheduler:
                 poked = np.asarray(out_tok).copy()
                 poked[victim, 0] = POISON_TOKEN
                 out_tok = jnp.asarray(poked)
-            n_act = sum(s is not None for s in slots)
             stats.note_dispatch(key, n_act, extent)
             self.metrics["decode_dispatches"] += 1
             self.metrics["occupied_row_steps"] += n_act
@@ -1969,24 +1996,39 @@ class SlotScheduler:
                 )
             else:
                 arrival_due = bool(pendreq) and pendreq[0].arrival <= tick
-            if any(s is not None and s.fill is not None for s in slots):
-                # prompt-consuming rows need this tick's tokens NOW (a
-                # fill transition switches a row's input source); fills
-                # always start at a boundary, so nothing should be
-                # pending — the harvest is a defensive no-op
-                harvest()
-                out_np = np.asarray(out_tok)
-                changed = False
-                for i, s in enumerate(slots):
-                    if s is None:
-                        continue
-                    s.pos += 1
-                    if s.fill is not None:
-                        if s.pos == len(s.fill):
-                            # prompt consumed: this dispatch emitted the
-                            # request's first real token (its next input
-                            # is the program output, like a decode row)
-                            s.fill = None
+            with span("serve.harvest"):
+                if any(s is not None and s.fill is not None for s in slots):
+                    # prompt-consuming rows need this tick's tokens NOW (a
+                    # fill transition switches a row's input source); fills
+                    # always start at a boundary, so nothing should be
+                    # pending — the harvest is a defensive no-op
+                    collect()
+                    out_np = np.asarray(out_tok)
+                    changed = False
+                    for i, s in enumerate(slots):
+                        if s is None:
+                            continue
+                        s.pos += 1
+                        if s.fill is not None:
+                            if s.pos == len(s.fill):
+                                # prompt consumed: this dispatch emitted the
+                                # request's first real token (its next input
+                                # is the program output, like a decode row)
+                                s.fill = None
+                                t_emit = int(out_np[i, 0])
+                                if t_emit == POISON_TOKEN:
+                                    quarantine(i, s)
+                                    changed = True
+                                    continue
+                                s.cur_tok = t_emit
+                                s.tokens.append(s.cur_tok)
+                                if s.first_wall is None:
+                                    s.first_wall = time.perf_counter()
+                                s.remaining = s.req.max_new - 1
+                            else:
+                                # mid-prompt rows feed host prompt tokens
+                                changed = True
+                        else:
                             t_emit = int(out_np[i, 0])
                             if t_emit == POISON_TOKEN:
                                 quarantine(i, s)
@@ -1996,50 +2038,35 @@ class SlotScheduler:
                             s.tokens.append(s.cur_tok)
                             if s.first_wall is None:
                                 s.first_wall = time.perf_counter()
-                            s.remaining = s.req.max_new - 1
-                        else:
-                            # mid-prompt rows feed host prompt tokens
-                            changed = True
-                    else:
-                        t_emit = int(out_np[i, 0])
-                        if t_emit == POISON_TOKEN:
-                            quarantine(i, s)
-                            changed = True
-                            continue
-                        s.cur_tok = t_emit
-                        s.tokens.append(s.cur_tok)
-                        if s.first_wall is None:
-                            s.first_wall = time.perf_counter()
-                        s.remaining -= 1
-                    if s.fill is None and s.remaining <= 0:
-                        retire(i, s)
-                        changed = True  # active set shrank: rebuild mask
-                dev_args = (None if changed or arrival_due
-                            else (out_tok, pos_dev + 1, mask_dev))
-            else:
-                # pure decode tick: budgets are host-side counters, so
-                # retirement needs no token values — defer the D2H sync
-                # and keep the loop device-resident until a boundary
-                # (a retire, or an arrival that may admit)
-                pending.append(out_tok)
-                boundary = arrival_due
-                for s in slots:
-                    if s is None:
-                        continue
-                    s.pos += 1
-                    s.remaining -= 1
-                    if s.remaining <= 0:
-                        boundary = True
-                if boundary:
-                    harvest()
-                    for i, s in enumerate(slots):
-                        if s is not None and s.remaining <= 0:
+                            s.remaining -= 1
+                        if s.fill is None and s.remaining <= 0:
                             retire(i, s)
-                    dev_args = None
+                            changed = True  # active set shrank: rebuild mask
+                    dev_args = (None if changed or arrival_due
+                                else (out_tok, pos_dev + 1, mask_dev))
                 else:
-                    dev_args = (out_tok, pos_dev + 1, mask_dev)
+                    # pure decode tick: budgets are host-side counters, so
+                    # retirement needs no token values — defer the D2H sync
+                    # and keep the loop device-resident until a boundary
+                    # (a retire, or an arrival that may admit)
+                    pending.append(out_tok)
+                    boundary = arrival_due
+                    for s in slots:
+                        if s is None:
+                            continue
+                        s.pos += 1
+                        s.remaining -= 1
+                        if s.remaining <= 0:
+                            boundary = True
+                    if boundary:
+                        collect()
+                        for i, s in enumerate(slots):
+                            if s is not None and s.remaining <= 0:
+                                retire(i, s)
+                        dev_args = None
+                    else:
+                        dev_args = (out_tok, pos_dev + 1, mask_dev)
             dt = time.perf_counter() - t_tick
-            tick_s.append(dt)
             if (self.tick_deadline_s is not None
                     and dt > self.tick_deadline_s):
                 return "deadline"
@@ -2069,7 +2096,8 @@ class SlotScheduler:
                 stats.note_fault(tick_degraded=True)
                 self.metrics["ticks_degraded"] += 1
             try:
-                directive = tick_once()
+                with span("serve.tick"):
+                    directive = tick_once()
             except Exception as e:
                 consec_failures += 1
                 self.metrics["tick_failures"] += 1
@@ -2129,7 +2157,6 @@ class SlotScheduler:
         m = self.metrics
         cap = max(m["capacity_row_steps"], 1)
         real_tokens = sum(len(r["tokens"]) for r in results.values())
-        tick_ms = np.asarray(tick_s) * 1e3
         out = {
             "results": results,
             "wall_s": wall,
@@ -2138,11 +2165,6 @@ class SlotScheduler:
             "occupancy": m["occupied_row_steps"] / cap,
             "pad_decode_fraction": 1.0 - m["occupied_row_steps"] / cap,
             "compiles": compiles,  # 0 after warmup covering the rungs
-            # tick-latency tail: inline compile stalls at cold rung
-            # crossings dominate p99/max; --async-compile absorbs them
-            "tick_ms_p50": float(np.percentile(tick_ms, 50)) if len(tick_ms) else 0.0,
-            "tick_ms_p99": float(np.percentile(tick_ms, 99)) if len(tick_ms) else 0.0,
-            "tick_ms_max": float(tick_ms.max()) if len(tick_ms) else 0.0,
             **m,
         }
         # SLO tails over per-request outcomes (wall-clock TTFT/latency)
@@ -2178,20 +2200,6 @@ class SlotScheduler:
                 pages_reused=ps_.pages_reused,
                 pages_reclaimed=ps_.pages_reclaimed,
             )
-            # surface the pool counters on the decode front + executor
-            # stats so bucket_report / the CLI transparency block print
-            # them alongside the bucketing numbers
-            stats.kv_pages_in_use = pool.pages_in_use
-            stats.kv_pages_capacity = pool.capacity
-            stats.kv_peak_pages_in_use = ps_.peak_pages_in_use
-            stats.kv_prefix_hits = ps_.prefix_hits
-            stats.kv_tokens_reused = ps_.tokens_reused
-            if srv.forge_module is not None:
-                es = srv.forge_module.stats
-                es.kv_pages_in_use = pool.pages_in_use
-                es.kv_peak_pages_in_use = ps_.peak_pages_in_use
-                es.kv_prefix_hits = ps_.prefix_hits
-                es.kv_tokens_reused = ps_.tokens_reused
         return out
 
     def _admit(self, admitted: List[int], slots: List[Optional[_Slot]],
@@ -2238,10 +2246,11 @@ class SlotScheduler:
             lengths[i] = P
         pargs = srv._prefill_args(extent, jtokens, 0, mask, lengths)
         try:
-            pmod, pkey, _ = srv.prefill_bucketed.program_for(
-                srv.params, cache, *pargs
-            )
-            logits, cache = pmod(srv.params, cache, *pargs)
+            with span("serve.prefill"):
+                pmod, pkey, _ = srv.prefill_bucketed.program_for(
+                    srv.params, cache, *pargs
+                )
+                logits, cache = pmod(srv.params, cache, *pargs)
         except Exception:
             # contained prefill failure (injected build/dispatch fault):
             # the contiguous cache owns its rows outright, so the slots
@@ -2378,10 +2387,11 @@ class SlotScheduler:
         pargs = (jnp.asarray(pt_host), jnp.asarray(tokens),
                  jnp.asarray(pos_np), jnp.asarray(mask))
         try:
-            pmod, pkey, _ = srv.prefill_bucketed.program_for(
-                srv.params, store, *pargs
-            )
-            logits, store = pmod(srv.params, store, *pargs)
+            with span("serve.prefill"):
+                pmod, pkey, _ = srv.prefill_bucketed.program_for(
+                    srv.params, store, *pargs
+                )
+                logits, store = pmod(srv.params, store, *pargs)
         except Exception:
             # a failed paged prefill must NOT fall back to fill-path
             # replay: prefix-hit rows hold forked (shared) pages, and a
@@ -2703,7 +2713,8 @@ def main(argv=None) -> int:
                   f"tokens_reused={res['tokens_reused']} "
                   f"reclaimed={res['pages_reclaimed']}")
             from repro.core.metrics import bucket_report
-            print(f"[serve] decode {bucket_report(server.bucketed.stats)}")
+            print(f"[serve] decode "
+                  f"{bucket_report(server.bucketed.stats, server.page_pool)}")
         return _compile_epilogue(server, args)
 
     warmup_s = server.warmup(sweep, prompt_lens=prompt_sweep)

@@ -322,7 +322,8 @@ def make_paged_serve_step(cfg: ModelConfig) -> Callable:
         logits, new_cache = model.paged_decode_step(
             params, cache, token, pos, cfg, slot_mask=slot_mask
         )
-        next_tok = guarded_argmax(logits[:, -1, :])
+        with jax.named_scope("logits"):
+            next_tok = guarded_argmax(logits[:, -1, :])
         new_store = {"k_pages": new_cache["k_pages"],
                      "v_pages": new_cache["v_pages"]}
         return next_tok[:, None], new_store

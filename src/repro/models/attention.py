@@ -146,21 +146,23 @@ def _paged_update_attend(
 
     pos_arr = jnp.asarray(cache_pos, jnp.int32)
     pos_row = jnp.broadcast_to(pos_arr, (B,)) if pos_arr.ndim == 0 else pos_arr
-    abs_pos = pos_row[:, None] + jnp.arange(sq, dtype=jnp.int32)[None, :]
-    page_idx = jnp.clip(abs_pos // ps, 0, MP - 1)
-    ok = abs_pos < max_len
-    if write_mask is not None:
-        ok = jnp.logical_and(ok, write_mask[:, None])
-    # trash-routed writes may collide (last-writer-wins): trash content is
-    # never unmasked, live destinations are uniquely owned per (row, pos)
-    page = jnp.where(
-        ok, jnp.take_along_axis(pt, page_idx, axis=1), 0
-    ).reshape(-1)
-    off = (abs_pos % ps).reshape(-1)
-    k_tok = k.transpose(0, 2, 1, 3).reshape(B * sq, KVH, D)
-    v_tok = v.transpose(0, 2, 1, 3).reshape(B * sq, KVH, D)
-    new_k = k_pages.at[page, :, off].set(k_tok)
-    new_v = v_pages.at[page, :, off].set(v_tok)
+    with jax.named_scope("kv.write"):
+        abs_pos = pos_row[:, None] + jnp.arange(sq, dtype=jnp.int32)[None, :]
+        page_idx = jnp.clip(abs_pos // ps, 0, MP - 1)
+        ok = abs_pos < max_len
+        if write_mask is not None:
+            ok = jnp.logical_and(ok, write_mask[:, None])
+        # trash-routed writes may collide (last-writer-wins): trash content
+        # is never unmasked, live destinations are uniquely owned per
+        # (row, pos)
+        page = jnp.where(
+            ok, jnp.take_along_axis(pt, page_idx, axis=1), 0
+        ).reshape(-1)
+        off = (abs_pos % ps).reshape(-1)
+        k_tok = k.transpose(0, 2, 1, 3).reshape(B * sq, KVH, D)
+        v_tok = v.transpose(0, 2, 1, 3).reshape(B * sq, KVH, D)
+        new_k = k_pages.at[page, :, off].set(k_tok)
+        new_v = v_pages.at[page, :, off].set(v_tok)
 
     if kv_kernel not in KV_KERNELS:
         raise ValueError(f"kv_kernel must be one of {KV_KERNELS}, got {kv_kernel!r}")
@@ -173,8 +175,9 @@ def _paged_update_attend(
         # must mirror the contiguous cache branch of attention() exactly:
         # same mask builders, same cache_pos rank, same sdpa — that is the
         # bitwise-equality contract tests/test_paged_kv.py enforces
-        k_view = _gather_pages(new_k, pt)
-        v_view = _gather_pages(new_v, pt)
+        with jax.named_scope("kv.gather"):
+            k_view = _gather_pages(new_k, pt)
+            v_view = _gather_pages(new_v, pt)
         if sq > 1:
             mask = L.prefill_length_mask(cache_pos, sq, max_len, window=window)
         elif window is not None:

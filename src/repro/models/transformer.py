@@ -163,24 +163,27 @@ def block_paged_decode(
     Unlike :func:`block_decode`, the slot mask rides *inside* the body:
     the page store has no batch axis to gate post hoc, so inactive rows'
     writes are routed to the trash page by the scatter itself."""
-    h = L.apply_norm(x, p["norm1"], cfg.norm)
-    attn_out, new_cache = A.attention(
-        h, p["attn"], n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-        rope_cos=cos, rope_sin=sin,
-        cache={"k_pages": k_pages, "v_pages": v_pages,
-               "page_table": page_table},
-        cache_pos=pos, write_mask=write_mask, kv_kernel=cfg.kv_kernel,
-    )
-    x = x + attn_out
-    h = L.apply_norm(x, p["norm2"], cfg.norm)
-    if cfg.family == "moe":
-        ffn_out = MOE.moe_ffn(
-            h, p["moe"], n_experts=cfg.n_experts, top_k=cfg.top_k,
-            capacity_factor=cfg.capacity_factor,
+    with jax.named_scope("attn"):
+        h = L.apply_norm(x, p["norm1"], cfg.norm)
+        attn_out, new_cache = A.attention(
+            h, p["attn"], n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+            rope_cos=cos, rope_sin=sin,
+            cache={"k_pages": k_pages, "v_pages": v_pages,
+                   "page_table": page_table},
+            cache_pos=pos, write_mask=write_mask, kv_kernel=cfg.kv_kernel,
         )
-    else:
-        ffn_out = L.apply_ffn(h, p["ffn"], cfg.ffn)
-    return x + ffn_out, new_cache["k_pages"], new_cache["v_pages"]
+        x = x + attn_out
+    with jax.named_scope("mlp"):
+        h = L.apply_norm(x, p["norm2"], cfg.norm)
+        if cfg.family == "moe":
+            ffn_out = MOE.moe_ffn(
+                h, p["moe"], n_experts=cfg.n_experts, top_k=cfg.top_k,
+                capacity_factor=cfg.capacity_factor,
+            )
+        else:
+            ffn_out = L.apply_ffn(h, p["ffn"], cfg.ffn)
+        x = x + ffn_out
+    return x, new_cache["k_pages"], new_cache["v_pages"]
 
 
 # --------------------------------------------------------------------------
@@ -403,8 +406,10 @@ def _paged_cached_forward(
             vs.append(nv)
         new_k, new_v = jnp.stack(ks), jnp.stack(vs)
 
-    x = L.apply_norm(x, params["final_norm"], cfg.norm)
-    logits = L.lm_head(x, params.get("lm_head", params["embed"]), transpose=cfg.tie_embeddings)
+    with jax.named_scope("logits"):
+        x = L.apply_norm(x, params["final_norm"], cfg.norm)
+        logits = L.lm_head(x, params.get("lm_head", params["embed"]),
+                           transpose=cfg.tie_embeddings)
     return logits, {"k_pages": new_k, "v_pages": new_v, "page_table": pt}
 
 
