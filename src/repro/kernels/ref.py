@@ -56,7 +56,8 @@ def sdpa_ref(
 def gather_pages(pages: jax.Array, page_table: jax.Array) -> jax.Array:
     """Gather a contiguous per-row KV view out of a paged store.
 
-    pages: (num_pages, KVH, page_size, D) — the flat page pool.
+    pages: (num_pages, KVH, page_size, D) — one layer's pages, head-major,
+    as the paged decode kernel reads them.
     page_table: (B, max_pages) int32 — per-row page indices; unallocated
     entries point at the trash page (0) and are masked out by the caller.
 

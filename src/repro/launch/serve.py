@@ -146,7 +146,10 @@ class BatchedServer:
     does not own caller buffers), so each decode step still materializes
     a fresh cache pytree on device (~2x cache memory at large
     ``max_len``).  Pooling recycles at admission granularity; per-step
-    in-place cache update needs caller-opt-in input donation.
+    in-place cache update needs caller-opt-in input donation.  With the
+    paged store the model step already writes in place inside its layer
+    loop (the stacked store rides in the loop's carry); what remains is
+    one copy of the non-donated input store into that carry per call.
     """
 
     def __init__(self, cfg, params, max_len: int = 256, mode: str = "jit",
@@ -201,8 +204,10 @@ class BatchedServer:
         self.kv_pages = kv_pages
         self.page_pool = None
         self.prefix_tree = None
-        #: server-resident {k_pages, v_pages} store (no batch axis);
-        #: every slot reads/writes it through its page-table row
+        #: server-resident {k_pages, v_pages} store (no batch axis),
+        #: each leaf (n_layers, num_pages, page_size, KVH * head_dim) in
+        #: token-major rows; every slot reads/writes it through its
+        #: page-table row, and the server never looks inside it
         self.page_store = None
         self.max_pages_per_slot = 0
         if self.paged:

@@ -107,6 +107,73 @@ def sdpa_unfused(
     return o.astype(v.dtype)
 
 
+def _gather_rows(store: jax.Array, layer: jax.Array, page_table: jax.Array,
+                 n_kv_heads: int, sq: int) -> jax.Array:
+    """One layer's per-row KV view out of the token-major store, in the
+    ``(B, KVH, MP * ps, D)`` layout a contiguous cache row has: the rows'
+    pages in one gather, then split into heads.
+
+    The head-major view is materialized in bf16 (``optimization_barrier``)
+    before attention reads it: left to itself, XLA on the TPU converts the
+    gathered rows to float32 first and re-lays them out at twice the
+    bytes.  A one-token decode step reads the view as whole rows; a
+    prefill chunk's matmuls read it as page tiles ``(B, KVH, MP, ps, D)``,
+    which keeps each page's ``(ps, D)`` block whole and the score matrix
+    out of memory.  Either way the values are the gathered ones, bit for
+    bit."""
+    B, MP = page_table.shape
+    ps = store.shape[2]
+    rows = store[layer, page_table].reshape(B, MP, ps, n_kv_heads, -1)
+    if sq == 1:
+        view = rows.reshape(B, MP * ps, n_kv_heads, -1).transpose(0, 2, 1, 3)
+        return lax.optimization_barrier(view)
+    tiles = lax.optimization_barrier(rows.transpose(0, 3, 1, 2, 4))
+    return tiles.reshape(B, n_kv_heads, MP * ps, -1)
+
+
+def _write_tokens(store: jax.Array, x: jax.Array, layer: jax.Array,
+                  page_table: jax.Array, pos_row: jax.Array,
+                  write_mask: Optional[jax.Array]) -> jax.Array:
+    """Write this step's K (or V), ``x`` ``(B, KVH, sq, D)``, into layer
+    ``layer`` of the token-major store at each row's positions
+    ``pos_row[b] + [0, sq)``, through the page table.
+
+    Rows outside ``write_mask`` (inactive slots) and pages past the table
+    extent (prefill pad) are routed to the reserved trash page 0, so the
+    store needs no batch axis and no post-hoc slot gate.  Trash-routed
+    writes may collide (last writer wins): trash content is never
+    unmasked, and live destinations are owned by one row each.
+
+    A decode step (``sq == 1``) scatters one whole row per batch row.  A
+    prefill chunk writes whole pages instead: it reads the pages its
+    tokens touch, lays the new rows over them and scatters the pages
+    back, since the TPU scatters a few large windows far faster than
+    many rows.  Either way only the touched pages change, bit for bit
+    as a row-by-row write would leave them."""
+    B, KVH, sq, D = x.shape
+    ps, row = store.shape[2], store.shape[3]
+    MP = page_table.shape[1]
+    rows = x.transpose(0, 2, 1, 3).reshape(B, sq, KVH * D)
+    # the pages a chunk of sq tokens can touch, at any offset in a page;
+    # those it does not touch at this row's offset go to the trash page
+    n = 1 if sq == 1 else (sq - 1) // ps + 2
+    j = jnp.arange(n, dtype=jnp.int32)[None, :]
+    idx = pos_row[:, None] // ps + j
+    ok = jnp.logical_and(idx < MP, j * ps < (pos_row % ps)[:, None] + sq)
+    if write_mask is not None:
+        ok = jnp.logical_and(ok, write_mask[:, None])
+    page = jnp.where(
+        ok, jnp.take_along_axis(page_table, jnp.clip(idx, 0, MP - 1), axis=1), 0
+    )
+    if sq == 1:
+        return store.at[layer, page[:, 0], pos_row % ps].set(rows[:, 0])
+    buf = store[layer, page].reshape(B, n * ps, row)
+    buf = jax.vmap(lambda b, r, o: lax.dynamic_update_slice(b, r, (o, 0)))(
+        buf, rows, pos_row % ps
+    )
+    return store.at[layer, page].set(buf.reshape(B, n, ps, row))
+
+
 def _paged_update_attend(
     q: jax.Array,  # (B, H, sq, D) post-RoPE queries
     k: jax.Array,  # (B, KVH, sq, D) post-RoPE keys for this step
@@ -114,61 +181,55 @@ def _paged_update_attend(
     cache: Dict[str, jax.Array],  # k_pages / v_pages / page_table
     cache_pos: jax.Array,  # scalar or per-row (B,) write position
     *,
+    layer: Any,  # int32 scalar: the layer of the stacked store
     window: Optional[int],
     write_mask: Optional[jax.Array],  # bool (B,) — rows allowed to write
     kv_kernel: str,  # one of KV_KERNELS
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Paged-cache decode/prefill: scatter this step's K/V into the flat
-    page pool through the page table, then attend over the row's pages.
+    """Paged-cache decode/prefill: scatter this step's K/V into the
+    stacked page store through the page table, then attend over the
+    row's pages.
 
-    The write is a per-token scatter ``flat[table[b, pos//ps]*ps + pos%ps]
-    = k`` — rows outside ``write_mask`` (inactive slots) and positions
-    past the table extent (prefill pad) are routed to the reserved trash
-    page 0, so the store needs no batch axis and no post-hoc slot gate.
-    The "ref" attend gathers the row's pages back into the exact
-    contiguous-cache layout and reuses the same masks + sdpa — the paged
-    path is **bitwise** the contiguous path on live rows (garbage beyond
-    ``pos``, trash reads included, lands on score columns already pinned
-    to the additive-mask floor).  "pallas" dispatches the page-table-
-    indirected decode kernel instead (see kernels/paged_attention.py),
-    compiled for the TPU; "interpret" runs that kernel in the Pallas
-    interpreter, for tests off the chip.
+    The store is ``(n_layers, num_pages, page_size, KVH * D)``: one row
+    per token holds all heads.  The write (:func:`_write_tokens`) is one
+    scatter into the whole store, which the layer loop carries, so XLA
+    updates it in place; masked rows and prefill pad land on the trash
+    page 0.
+
+    The "ref" attend gathers the rows' pages of this layer back into the
+    exact contiguous-cache layout and reuses the same masks + sdpa — the
+    paged path is **bitwise** the contiguous path on live rows (garbage
+    beyond ``pos``, trash reads included, lands on score columns already
+    pinned to the additive-mask floor).  "pallas" dispatches the page-
+    table-indirected decode kernel instead (see
+    kernels/paged_attention.py), compiled for the TPU, on this layer's
+    pages re-laid out head-major, the kernel's own interface;
+    "interpret" runs that kernel in the Pallas interpreter, for tests off
+    the chip.
     """
     from ..kernels.paged_attention import paged_attention as _paged_kernel
-    from ..kernels.ref import gather_pages as _gather_pages
 
-    k_pages, v_pages = cache["k_pages"], cache["v_pages"]
+    k_store, v_store = cache["k_pages"], cache["v_pages"]
     pt = cache["page_table"].astype(jnp.int32)
-    NP, KVH, ps, D = k_pages.shape
-    B, MP = pt.shape
-    max_len = MP * ps
-    sq = q.shape[2]
+    _, NP, ps, _ = k_store.shape
+    B, KVH, sq, D = k.shape
+    max_len = pt.shape[1] * ps
+    layer = jnp.asarray(layer, jnp.int32)
 
     pos_arr = jnp.asarray(cache_pos, jnp.int32)
     pos_row = jnp.broadcast_to(pos_arr, (B,)) if pos_arr.ndim == 0 else pos_arr
     with jax.named_scope("kv.write"):
-        abs_pos = pos_row[:, None] + jnp.arange(sq, dtype=jnp.int32)[None, :]
-        page_idx = jnp.clip(abs_pos // ps, 0, MP - 1)
-        ok = abs_pos < max_len
-        if write_mask is not None:
-            ok = jnp.logical_and(ok, write_mask[:, None])
-        # trash-routed writes may collide (last-writer-wins): trash content
-        # is never unmasked, live destinations are uniquely owned per
-        # (row, pos)
-        page = jnp.where(
-            ok, jnp.take_along_axis(pt, page_idx, axis=1), 0
-        ).reshape(-1)
-        off = (abs_pos % ps).reshape(-1)
-        k_tok = k.transpose(0, 2, 1, 3).reshape(B * sq, KVH, D)
-        v_tok = v.transpose(0, 2, 1, 3).reshape(B * sq, KVH, D)
-        new_k = k_pages.at[page, :, off].set(k_tok)
-        new_v = v_pages.at[page, :, off].set(v_tok)
+        new_k = _write_tokens(k_store, k, layer, pt, pos_row, write_mask)
+        new_v = _write_tokens(v_store, v, layer, pt, pos_row, write_mask)
 
     if kv_kernel not in KV_KERNELS:
         raise ValueError(f"kv_kernel must be one of {KV_KERNELS}, got {kv_kernel!r}")
     if kv_kernel != "ref" and sq == 1:
+        def head_major(store):
+            return store[layer].reshape(NP, ps, KVH, D).transpose(0, 2, 1, 3)
+
         out = _paged_kernel(
-            q[:, :, 0, :], new_k, new_v, pt, pos_row,
+            q[:, :, 0, :], head_major(new_k), head_major(new_v), pt, pos_row,
             window=window, interpret=kv_kernel == "interpret",
         )[:, :, None, :].astype(v.dtype)
     else:
@@ -176,8 +237,8 @@ def _paged_update_attend(
         # same mask builders, same cache_pos rank, same sdpa — that is the
         # bitwise-equality contract tests/test_paged_kv.py enforces
         with jax.named_scope("kv.gather"):
-            k_view = _gather_pages(new_k, pt)
-            v_view = _gather_pages(new_v, pt)
+            k_view = _gather_rows(new_k, layer, pt, KVH, sq)
+            v_view = _gather_rows(new_v, layer, pt, KVH, sq)
         if sq > 1:
             mask = L.prefill_length_mask(cache_pos, sq, max_len, window=window)
         elif window is not None:
@@ -206,6 +267,7 @@ def attention(
     cache: Optional[Dict[str, jax.Array]] = None,
     cache_pos: Optional[jax.Array] = None,
     cache_valid_len: Optional[jax.Array] = None,  # rotating-buffer masks
+    kv_layer: Any = 0,  # int32 scalar — paged cache only: layer of the store
     write_mask: Optional[jax.Array] = None,  # bool (B,) — paged cache only
     kv_kernel: str = "ref",  # paged-cache attend impl (see above)
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
@@ -241,7 +303,7 @@ def attention(
                 "feature; paged rows are length-masked through pos"
             )
         out, new_cache = _paged_update_attend(
-            q, k, v, cache, cache_pos,
+            q, k, v, cache, cache_pos, layer=kv_layer,
             window=window, write_mask=write_mask, kv_kernel=kv_kernel,
         )
     elif cache is not None:

@@ -149,8 +149,9 @@ def block_decode(
 def block_paged_decode(
     p: Params,
     x: jax.Array,
-    k_pages: jax.Array,  # (num_pages, KVH, page_size, D) — this layer's pool
-    v_pages: jax.Array,
+    k_store: jax.Array,  # (n_layers, num_pages, page_size, KVH * D)
+    v_store: jax.Array,
+    layer: jax.Array,  # int32 scalar: this block's layer in the store
     page_table: jax.Array,  # (B, max_pages) int32, shared by all layers
     pos: jax.Array,  # scalar or per-row (B,) write position
     write_mask: jax.Array,  # bool (B,) — rows allowed to write (slot mask)
@@ -160,6 +161,11 @@ def block_paged_decode(
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Block body against the paged KV pool (decode and chunked prefill).
 
+    The body takes the whole stacked store and its ``layer`` index and
+    returns the whole store with this step's tokens written into that
+    layer's rows, so the layer loop carries the store and XLA updates it
+    in place (no per-layer slab is sliced out or written back).
+
     Unlike :func:`block_decode`, the slot mask rides *inside* the body:
     the page store has no batch axis to gate post hoc, so inactive rows'
     writes are routed to the trash page by the scatter itself."""
@@ -168,9 +174,10 @@ def block_paged_decode(
         attn_out, new_cache = A.attention(
             h, p["attn"], n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
             rope_cos=cos, rope_sin=sin,
-            cache={"k_pages": k_pages, "v_pages": v_pages,
+            cache={"k_pages": k_store, "v_pages": v_store,
                    "page_table": page_table},
-            cache_pos=pos, write_mask=write_mask, kv_kernel=cfg.kv_kernel,
+            cache_pos=pos, kv_layer=layer, write_mask=write_mask,
+            kv_kernel=cfg.kv_kernel,
         )
         x = x + attn_out
     with jax.named_scope("mlp"):
@@ -283,9 +290,16 @@ def init_paged_cache(
     num_pages: int,
     page_size: int,
 ) -> Dict[str, jax.Array]:
-    """Paged decode state: one flat page pool per layer plus one page
-    table shared by every layer (a logical page holds all layers' K/V for
-    its token block, so the allocator hands out one index per block).
+    """Paged decode state: a page store per K and V plus one page table
+    shared by every layer (a logical page holds all layers' K/V for its
+    token block, so the allocator hands out one index per block).
+
+    Each store is ``(n_layers, num_pages, page_size, n_kv_heads *
+    head_dim)``: token-major rows, one row holding one token's K (or V)
+    for every head.  A step writes whole rows (all heads of a token at
+    once), and the flat row keeps the minor dimension a multiple of 128
+    lanes where a head alone (96 at phi3) is not, so the store is never
+    padded or re-laid out around the write.
 
     Page 0 is the reserved trash page (see core/paging.py): a zero-filled
     table points every slot there, masked/pad writes scatter there, and
@@ -294,7 +308,7 @@ def init_paged_cache(
     if max_len % page_size:
         raise ValueError(f"max_len {max_len} not a multiple of page_size {page_size}")
     dt = _dtype(cfg)
-    shape = (cfg.n_layers, num_pages, cfg.n_kv_heads, page_size, cfg.head_dim_)
+    shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads * cfg.head_dim_)
     return {
         "k_pages": jnp.zeros(shape, dt),
         "v_pages": jnp.zeros(shape, dt),
@@ -376,7 +390,13 @@ def _paged_cached_forward(
     """:func:`_cached_forward` against the paged KV pool.  The page table
     is read-only inside the model (allocation is host-side, in the serve
     layer); the slot mask rides inside the body because the batch-free
-    page store cannot be gated per row after the fact."""
+    page store cannot be gated per row after the fact.
+
+    The stacked store rides in the layer loop's carry, beside ``x``, and
+    each block writes its tokens into its own layer's rows of it in
+    place: the loop's ``xs`` are the layer weights and the layer index
+    only, so no layer's slab is sliced out of the store or stacked back
+    into a new one."""
     B = x.shape[0]
     mask = (jnp.ones((B,), jnp.bool_) if slot_mask is None
             else jnp.asarray(slot_mask, jnp.bool_))
@@ -385,32 +405,30 @@ def _paged_cached_forward(
         jax.tree_util.tree_map(lambda a: a[0], params["blocks"])
         if cfg.scan_layers else params["blocks"][0]
     )
-    k0, v0 = cache["k_pages"][0], cache["v_pages"][0]
-    body = _body_fn(cfg, mode, (one_block, x, k0, v0, pt, pos, mask, cos, sin))
+    k_store, v_store = cache["k_pages"], cache["v_pages"]
+    layer0 = jnp.zeros((), jnp.int32)
+    body = _body_fn(cfg, mode, (one_block, x, k_store, v_store, layer0, pt,
+                                pos, mask, cos, sin))
 
     if cfg.scan_layers:
         def step(carry, xs):
-            p_layer, kp, vp = xs
-            y, nk, nv = body(p_layer, carry, kp, vp, pt, pos, mask, cos, sin)
-            return y, (nk, nv)
+            p_layer, layer = xs
+            return body(p_layer, *carry, layer, pt, pos, mask, cos, sin), None
 
-        x, (new_k, new_v) = lax.scan(
-            step, x, (params["blocks"], cache["k_pages"], cache["v_pages"])
+        layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+        (x, k_store, v_store), _ = lax.scan(
+            step, (x, k_store, v_store), (params["blocks"], layers)
         )
     else:
-        ks, vs = [], []
         for i, p_layer in enumerate(params["blocks"]):
-            x, nk, nv = body(p_layer, x, cache["k_pages"][i],
-                             cache["v_pages"][i], pt, pos, mask, cos, sin)
-            ks.append(nk)
-            vs.append(nv)
-        new_k, new_v = jnp.stack(ks), jnp.stack(vs)
+            x, k_store, v_store = body(p_layer, x, k_store, v_store,
+                                       jnp.int32(i), pt, pos, mask, cos, sin)
 
     with jax.named_scope("logits"):
         x = L.apply_norm(x, params["final_norm"], cfg.norm)
         logits = L.lm_head(x, params.get("lm_head", params["embed"]),
                            transpose=cfg.tie_embeddings)
-    return logits, {"k_pages": new_k, "v_pages": new_v, "page_table": pt}
+    return logits, {"k_pages": k_store, "v_pages": v_store, "page_table": pt}
 
 
 def paged_decode_step(

@@ -365,9 +365,10 @@ class TestPagedDecodeFidelity:
         for b in range(B):
             pt[b] = 1 + b * MP + np.arange(MP)
         pt_dev = jnp.asarray(pt)
+        # a one-layer token-major store: (layers, pages, ps, KVH * D)
         store = {
-            "k_pages": jnp.zeros((1 + B * MP, KVH, ps, D), jnp.float32),
-            "v_pages": jnp.zeros((1 + B * MP, KVH, ps, D), jnp.float32),
+            "k_pages": jnp.zeros((1, 1 + B * MP, ps, KVH * D), jnp.float32),
+            "v_pages": jnp.zeros((1, 1 + B * MP, ps, KVH * D), jnp.float32),
         }
         rng = np.random.default_rng(11)
         mask = jnp.ones((B,), bool)
@@ -382,8 +383,145 @@ class TestPagedDecodeFidelity:
             ob, store = attention(x, p, n_heads=H, n_kv_heads=KVH,
                                   window=window,
                                   cache={**store, "page_table": pt_dev},
-                                  cache_pos=pos, write_mask=mask)
+                                  cache_pos=pos, kv_layer=0,
+                                  write_mask=mask)
             np.testing.assert_array_equal(np.asarray(oa), np.asarray(ob))
+
+
+class TestTokenMajorStore:
+    """The stacked store (layers, pages, page_size, KVH * D) rides in the
+    layer loop's carry: a step writes whole token rows into its own
+    layer's pages and leaves every other row of the store bitwise as it
+    was."""
+
+    B, MAX_LEN, PS = 2, 32, 8
+
+    def _random_store(self, model, cfg, seed):
+        cache = _identity_paged_cache(model, cfg, self.B, self.MAX_LEN,
+                                      self.PS)
+        rng = np.random.default_rng(seed)
+        for name in ("k_pages", "v_pages"):
+            cache[name] = jnp.asarray(
+                rng.standard_normal(cache[name].shape), cache[name].dtype)
+        return cache
+
+    def test_init_paged_cache_is_token_major(self, fid_setup):
+        cfg, model, _ = fid_setup
+        cache = model.init_paged_cache(cfg, self.B, self.MAX_LEN,
+                                       num_pages=9, page_size=self.PS)
+        row = cfg.n_kv_heads * cfg.head_dim_
+        for name in ("k_pages", "v_pages"):
+            assert cache[name].shape == (cfg.n_layers, 9, self.PS, row)
+            assert cache[name].dtype == jnp.dtype(cfg.dtype)
+            assert not np.any(np.asarray(cache[name]))
+        assert cache["page_table"].shape == (self.B, self.MAX_LEN // self.PS)
+
+    def test_decode_writes_only_live_rows_in_each_layer(self, fid_setup):
+        """Row 0 live at position 5, row 1 masked: in every layer exactly
+        row 0's slot (its page for position 5, offset 5 % ps) changes;
+        every other row of every real page keeps its bits."""
+        cfg, model, params = fid_setup
+        cache = self._random_store(model, cfg, seed=21)
+        pt = np.asarray(cache["page_table"])
+        tok = jnp.asarray([[3], [5]], jnp.int32)
+        pos = jnp.asarray([5, 9], jnp.int32)
+        _, out = model.paged_decode_step(params, cache, tok, pos, cfg,
+                                         slot_mask=jnp.asarray([True, False]))
+        want = np.zeros(cache["k_pages"].shape[:3], bool)
+        want[:, pt[0, 5 // self.PS], 5 % self.PS] = True
+        for name in ("k_pages", "v_pages"):
+            changed = np.any(np.asarray(out[name]) != np.asarray(cache[name]),
+                             axis=-1)
+            # page 0 is the trash page the masked row's write went to
+            np.testing.assert_array_equal(changed[:, 1:], want[:, 1:],
+                                          err_msg=name)
+
+    def test_prefill_chunk_writes_only_its_positions(self, fid_setup):
+        """A prefill chunk written as whole pages, at offsets inside a page
+        (row 0 from position 3, row 1 from 10; row 2 masked): in every
+        layer exactly each live row's positions [pos, pos + S) change, and
+        the rest of the pages the chunk touches keeps its bits."""
+        cfg, model, params = fid_setup
+        B, S = 3, 6
+        cache = model.init_paged_cache(cfg, B, self.MAX_LEN,
+                                       num_pages=1 + B * 4, page_size=self.PS)
+        rng = np.random.default_rng(25)
+        pt = np.stack([1 + b * 4 + rng.permutation(4) for b in range(B)])
+        cache["page_table"] = jnp.asarray(pt, jnp.int32)
+        for name in ("k_pages", "v_pages"):
+            cache[name] = jnp.asarray(
+                rng.standard_normal(cache[name].shape), cache[name].dtype)
+        toks = jnp.asarray(rng.integers(0, cfg.vocab, (B, S)), jnp.int32)
+        pos = np.asarray([3, 10, 0], np.int32)
+        _, out = model.paged_prefill_step(
+            params, cache, toks, jnp.asarray(pos), cfg,
+            slot_mask=jnp.asarray([True, True, False]))
+        want = np.zeros(cache["k_pages"].shape[:3], bool)
+        for b in (0, 1):
+            for t in range(pos[b], pos[b] + S):
+                want[:, pt[b, t // self.PS], t % self.PS] = True
+        for name in ("k_pages", "v_pages"):
+            changed = np.any(np.asarray(out[name]) != np.asarray(cache[name]),
+                             axis=-1)
+            np.testing.assert_array_equal(changed[:, 1:], want[:, 1:],
+                                          err_msg=name)
+
+    @pytest.mark.parametrize("layer", [0, 1, 2])
+    def test_layer_write_stays_in_its_layer(self, layer):
+        """Attention against layer ``layer`` of a three-layer store writes
+        and reads that layer alone: the other layers keep their bits, and
+        output and written layer equal a one-layer store holding it."""
+        H, KVH, D, max_len, ps, B, d_model = 4, 2, 8, 32, 8, 2, 32
+        MP = max_len // ps
+        p = attn_init(jax.random.PRNGKey(3), d_model, H, KVH, D,
+                      dtype=jnp.float32)
+        rng = np.random.default_rng(4)
+        pt = np.zeros((B, MP), np.int32)
+        for b in range(B):
+            pt[b] = 1 + b * MP + rng.permutation(MP)
+        shape = (3, 1 + B * MP, ps, KVH * D)
+        store = {n: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+                 for n in ("k_pages", "v_pages")}
+        x = jnp.asarray(rng.standard_normal((B, 1, d_model)), jnp.float32)
+        pos = jnp.asarray([6, 17], jnp.int32)
+        mask = jnp.ones((B,), bool)
+        kw = dict(n_heads=H, n_kv_heads=KVH, cache_pos=pos, write_mask=mask)
+        out, new = attention(x, p, cache={**store, "page_table": jnp.asarray(pt)},
+                             kv_layer=layer, **kw)
+        one = {n: store[n][layer:layer + 1] for n in store}
+        out1, new1 = attention(x, p, cache={**one, "page_table": jnp.asarray(pt)},
+                               kv_layer=0, **kw)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(out1))
+        for n in store:
+            got, was = np.asarray(new[n]), np.asarray(store[n])
+            np.testing.assert_array_equal(got[layer], np.asarray(new1[n])[0])
+            others = [i for i in range(3) if i != layer]
+            np.testing.assert_array_equal(got[others], was[others])
+            assert np.any(got[layer] != was[layer])
+
+    def test_unrolled_layers_decode_bitwise(self, fid_setup):
+        """``scan_layers=False`` threads the store through a Python layer
+        loop with the same body: still bitwise the contiguous cache."""
+        cfg, model, params = fid_setup
+        cfg = cfg.with_(scan_layers=False)
+        params = dict(params, blocks=[
+            jax.tree_util.tree_map(lambda a, i=i: a[i], params["blocks"])
+            for i in range(cfg.n_layers)
+        ])
+        cache = model.init_cache(cfg, self.B, self.MAX_LEN)
+        pcache = _identity_paged_cache(model, cfg, self.B, self.MAX_LEN,
+                                       self.PS)
+        toks = np.stack([_tokens(5, seed=23, vocab=cfg.vocab),
+                         _tokens(5, seed=24, vocab=cfg.vocab)])
+        mask = jnp.ones((self.B,), bool)
+        for t in range(5):
+            tok = jnp.asarray(toks[:, t:t + 1])
+            pos = jnp.full((self.B,), t, jnp.int32)
+            la, cache = model.decode_step(params, cache, tok, pos, cfg,
+                                          slot_mask=mask)
+            lb, pcache = model.paged_decode_step(params, pcache, tok, pos,
+                                                 cfg, slot_mask=mask)
+            np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
 
 
 class TestPagedSchedulerFidelity:
