@@ -16,6 +16,7 @@ that the fp8 pass puts first, at the same positions.
 from __future__ import annotations
 
 import importlib.util
+import sys
 from pathlib import Path
 from typing import Dict, List
 
@@ -25,14 +26,26 @@ import numpy as np
 
 from . import weights
 
-HERE = Path(__file__).resolve().parents[1]
+#: where a configuration's ``reference`` module is found by name
+REFERENCE_DIR = Path(__file__).resolve().parents[1] / "reference"
 
 
 def load_reference(name: str):
-    path = HERE / "reference" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"perfbench_reference_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    """The configuration's plain reference module.  It gives
+    ``program_fields(cfg)`` (the program's config fields it stands for),
+    ``layout(cfg)`` (see :mod:`bench.weights`), ``embed(globals, tokens)``,
+    ``make_layer_fns(cfg, precision)`` (one ``(h, layer weights) -> h`` per
+    layout group), ``make_head_fn(cfg, precision)`` (``(h, globals) ->
+    logits``) and ``counted_work(cfg)`` (see :mod:`bench.flops`).  Weights
+    are float32, by the layout's names.  Loaded once per process."""
+    path = REFERENCE_DIR / f"{name}.py"
+    key = f"perfbench_reference_{name}"
+    mod = sys.modules.get(key)
+    if mod is None or Path(mod.__file__) != path:
+        spec = importlib.util.spec_from_file_location(key, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[key] = mod  # a dataclass looks its module up there
+        spec.loader.exec_module(mod)
     return mod
 
 
@@ -75,11 +88,12 @@ def reference_gaps(cfg: Dict, seed: int, prompts: List[np.ndarray],
                    rows_per_chunk: int = 256) -> Dict[str, np.ndarray]:
     """Per served token: ``gap`` of the served token in the float32
     reference and, with ``control``, ``control_gap``: the float32 gap of
-    the token the fp8 pass puts first.  One layer's weights at a time."""
+    the token the fp8 pass puts first.  One layer's weights at a time, each
+    through the layer function of its group."""
     ref = load_reference(cfg["reference"])
-    glob, per_layer = ref.layout(cfg)
+    glob, groups = ref.layout(cfg)
     dtype = cfg["torch_dtype"]
-    make_layer = weights.layer_maker(glob, per_layer, dtype)
+    make_layer = weights.layer_maker(glob, groups, dtype)
     make_glob = weights.global_maker(glob, dtype)
     seqs = [np.concatenate([p, s[:-1]]).astype(np.int32) for p, s in zip(prompts, served)]
     # powers of two, so that a handful of compiled shapes serve every run;
@@ -92,21 +106,22 @@ def reference_gaps(cfg: Dict, seed: int, prompts: List[np.ndarray],
     rows_s = np.concatenate([len(p) - 1 + np.arange(len(s)) for p, s in zip(prompts, served)])
     target = np.concatenate(served).astype(np.int32)
 
-    def hidden_rows(precision: str):
-        layer_fn = ref.make_layer_fn(cfg, precision)
-        h = jnp.take(make_glob(seed, ["embed"])["embed"], jnp.asarray(toks), axis=0)
-        for layer in range(int(cfg["num_hidden_layers"])):
-            h = layer_fn(h, make_layer(seed, layer))
+    def hidden_rows(precision: str, g):
+        layer_fns = ref.make_layer_fns(cfg, precision)
+        h = ref.embed(g, jnp.asarray(toks))
+        for layer in range(weights.n_layers(groups)):
+            group, w = make_layer(seed, layer)
+            h = layer_fns[group](h, w)
         return h[jnp.asarray(rows_b), jnp.asarray(rows_s)]
 
     def logits_chunks(precision: str):
         head_fn = ref.make_head_fn(cfg, precision)
-        g = make_glob(seed, ["final_norm", "lm_head"])
-        h = hidden_rows(precision)
+        g = make_glob(seed)
+        h = hidden_rows(precision, g)
         n = h.shape[0]
         h = jnp.pad(h, ((0, -n % rows_per_chunk), (0, 0)))
         for a in range(0, n, rows_per_chunk):
-            lg = head_fn(h[a:a + rows_per_chunk], g["final_norm"], g["lm_head"])
+            lg = head_fn(h[a:a + rows_per_chunk], g)
             yield a, lg[: min(rows_per_chunk, n - a)]
 
     with jax.default_matmul_precision("highest"):

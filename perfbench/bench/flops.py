@@ -1,13 +1,16 @@
 """Operations and bytes of the serving work, counted from shapes.
 
-Counted whatever implements it, and only the work the model needs:
+Counted whatever implements it, and only the work the model needs.  What
+a configuration counts comes from its reference module's
+``counted_work(cfg)``, an object with the terms of :class:`Work`; this
+module adds them up over a window:
 
-* FLOPs per token: 2 x the matmul parameters (every layer's attention and
-  feed-forward weights plus the LM head; the embedding is a gather), plus
-  attention, 4 x layers x heads x head_dim x keys attended (QK^T and PV);
-* bytes per dispatch: every weight once (matmul weights in the served dtype,
-  norm scales in float32), plus the embedding rows gathered; per token the
-  KV it writes and the live context it reads (all layers, K and V).
+* FLOPs per token: 2 x the matmul parameters a token passes through (the
+  active ones), plus attention over the keys it attends;
+* bytes: per dispatch the weights it reads, given the tokens in it (every
+  weight once for a dense model; an expert layer reads the experts its
+  tokens touch); per token the KV it writes, the live context it reads and
+  the embedding row it gathers.
 
 The peaks come from ``peaks.json``, keyed by ``device_kind``; an unknown
 kind is an error.
@@ -15,9 +18,8 @@ kind is an error.
 from __future__ import annotations
 
 import json
-from dataclasses import dataclass
 from pathlib import Path
-from typing import Dict, Iterable, Tuple
+from typing import Dict, Iterable, Protocol, Tuple
 
 PEAKS = Path(__file__).with_name("peaks.json")
 
@@ -29,84 +31,66 @@ def peaks(device_kind: str) -> Dict[str, float]:
     return table[device_kind]
 
 
-@dataclass(frozen=True)
-class Dims:
-    layers: int
-    d: int
-    ff: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    vocab: int
-    bytes_per: int = 2
+class Work(Protocol):
+    """The per-configuration terms of the count."""
 
-    @classmethod
-    def of(cls, cfg: Dict) -> "Dims":
-        d, H = cfg["hidden_size"], cfg["num_attention_heads"]
-        return cls(layers=cfg["num_hidden_layers"], d=d, ff=cfg["intermediate_size"],
-                   heads=H, kv_heads=cfg["num_key_value_heads"],
-                   head_dim=cfg.get("head_dim") or d // H, vocab=cfg["vocab_size"],
-                   bytes_per={"bfloat16": 2, "float16": 2, "float32": 4}[cfg["torch_dtype"]])
-
-    @property
-    def matmul_params(self) -> int:
-        q = self.heads * self.head_dim
-        kv = self.kv_heads * self.head_dim
-        per_layer = self.d * q + 2 * self.d * kv + q * self.d + 3 * self.d * self.ff
-        return self.layers * per_layer + self.d * self.vocab
-
-    @property
-    def weight_bytes(self) -> int:
-        """Bytes every dispatch reads once: matmul weights and norm scales."""
-        return self.matmul_params * self.bytes_per + (2 * self.layers + 1) * self.d * 4
-
-    @property
-    def kv_bytes_per_token(self) -> int:
-        return 2 * self.layers * self.kv_heads * self.head_dim * self.bytes_per
+    #: matmul parameters one token passes through (active experts only)
+    matmul_params: int
+    #: KV bytes one token writes, and one attended key reads (all layers)
+    kv_bytes_per_token: int
+    #: bytes of the embedding row one token gathers
+    embed_bytes_per_token: int
 
     def attn_flops(self, keys: int) -> int:
-        return 4 * self.layers * self.heads * self.head_dim * keys
+        """FLOPs of attention over ``keys`` keys, summed over the tokens."""
 
-    def token_flops(self, keys: int) -> int:
-        return 2 * self.matmul_params + self.attn_flops(keys)
+    def dispatch_weight_bytes(self, tokens: float) -> float:
+        """Weight bytes one dispatch of ``tokens`` tokens reads."""
 
 
-def prefill_work(dims: Dims, prompt: int, skip: int) -> Tuple[int, int]:
+def prefill_work(counted: Work, prompt: int, skip: int) -> Tuple[int, int]:
     """(FLOPs, bytes other than weights) to prefill positions skip..prompt-1."""
     n = prompt - skip
     keys = (skip + 1 + prompt) * n // 2  # sum of p + 1 over the chunk
-    flops = 2 * dims.matmul_params * n + dims.attn_flops(keys)
-    kvb = dims.kv_bytes_per_token
-    return flops, prompt * kvb + n * kvb + n * dims.d * dims.bytes_per
+    flops = 2 * counted.matmul_params * n + counted.attn_flops(keys)
+    kvb = counted.kv_bytes_per_token
+    return flops, prompt * kvb + n * kvb + n * counted.embed_bytes_per_token
 
 
-def decode_work(dims: Dims, prompt: int, served: int) -> Tuple[int, int]:
+def decode_work(counted: Work, prompt: int, served: int) -> Tuple[int, int]:
     """(FLOPs, bytes other than weights) of the ``served - 1`` decode steps
     after a prefill of ``prompt`` tokens (the first token came from it)."""
     n = max(served - 1, 0)
     keys = sum(prompt + j for j in range(1, n + 1))
-    flops = 2 * dims.matmul_params * n + dims.attn_flops(keys)
-    kvb = dims.kv_bytes_per_token
-    return flops, keys * kvb + n * kvb + n * dims.d * dims.bytes_per
+    flops = 2 * counted.matmul_params * n + counted.attn_flops(keys)
+    kvb = counted.kv_bytes_per_token
+    return flops, keys * kvb + n * kvb + n * counted.embed_bytes_per_token
 
 
-def window_work(dims: Dims, requests: Iterable[Tuple[int, int, int]],
+def window_work(counted: Work, requests: Iterable[Tuple[int, int, int]],
                 prefill_dispatches: int, decode_dispatches: int) -> Dict[str, float]:
     """Totals over ``(prompt, skip, served)`` per request and the window's
-    dispatch counts."""
+    dispatch counts; each phase's weight bytes per dispatch are taken at its
+    mean tokens per dispatch (tokens prefilled, tokens decoded)."""
     fp = bp = fd = bd = 0
+    n_pre = n_dec = 0
     for prompt, skip, served in requests:
         if served < 1:
             continue
-        f, b = prefill_work(dims, prompt, skip)
+        f, b = prefill_work(counted, prompt, skip)
         fp, bp = fp + f, bp + b
-        f, b = decode_work(dims, prompt, served)
+        f, b = decode_work(counted, prompt, served)
         fd, bd = fd + f, bd + b
+        n_pre, n_dec = n_pre + prompt - skip, n_dec + served - 1
+
+    def weights(tokens: int, dispatches: int):
+        return dispatches * counted.dispatch_weight_bytes(tokens / dispatches) if dispatches else 0
+
     return {
         "prefill_flops": float(fp),
-        "prefill_bytes": float(bp + prefill_dispatches * dims.weight_bytes),
+        "prefill_bytes": float(bp + weights(n_pre, prefill_dispatches)),
         "decode_flops": float(fd),
-        "decode_bytes": float(bd + decode_dispatches * dims.weight_bytes),
+        "decode_bytes": float(bd + weights(n_dec, decode_dispatches)),
     }
 
 

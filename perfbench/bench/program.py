@@ -15,25 +15,24 @@ from typing import Any, Dict, List, Optional
 import jax
 import numpy as np
 
-from . import weights
+from . import correct, weights
 
 ENTRY = dict(mode="forge", backend="segment_jit", paged=True)
 
 
 def program_config(cfg: Dict):
-    """The program's ModelConfig for a configuration file; refuses drift."""
+    """The program's ModelConfig for a configuration file; refuses drift.
+
+    The program's own config for ``program.arch`` with the file's depth and
+    dtype and any ``program.overrides``; every field the reference's
+    ``program_fields(cfg)`` names has to read as it says."""
     from repro.configs import get_config
 
     prog = cfg["program"]
     mc = get_config(prog["arch"], smoke=bool(prog.get("smoke", False)))
-    mc = mc.with_(n_layers=int(cfg["num_hidden_layers"]), dtype=cfg["torch_dtype"])
-    want = {
-        "d_model": cfg["hidden_size"], "d_ff": cfg["intermediate_size"],
-        "n_heads": cfg["num_attention_heads"], "n_kv_heads": cfg["num_key_value_heads"],
-        "vocab": cfg["vocab_size"], "rope_theta": float(cfg["rope_theta"]),
-        "tie_embeddings": bool(cfg["tie_word_embeddings"]), "ffn": "swiglu",
-        "norm": "rmsnorm", "family": "dense",
-    }
+    mc = mc.with_(n_layers=int(cfg["num_hidden_layers"]), dtype=cfg["torch_dtype"],
+                  **prog.get("overrides", {}))
+    want = correct.load_reference(cfg["reference"]).program_fields(cfg)
     got = {k: getattr(mc, k) for k in want}
     if got != want:
         raise SystemExit(f"program config {mc.name} differs from {cfg['name']}: {got} != {want}")
@@ -41,21 +40,35 @@ def program_config(cfg: Dict):
 
 
 def program_params(cfg: Dict, seed: int, ref) -> Dict[str, Any]:
-    """Seeded weights in the program's pytree, made in one jitted call."""
-    glob, per_layer = ref.layout(cfg)
-    g, layers = weights.make_all(seed, glob, per_layer, int(cfg["num_hidden_layers"]),
-                                 cfg["torch_dtype"])
-    return {
-        "embed": g["embed"],
-        "final_norm": {"scale": g["final_norm"]},
-        "lm_head": g["lm_head"],
-        "blocks": {
-            "norm1": {"scale": layers["attn_norm"]},
-            "attn": {k: layers[k] for k in ("wq", "wk", "wv", "wo")},
-            "norm2": {"scale": layers["ffn_norm"]},
-            "ffn": {k: layers[k] for k in ("w_gate", "w_up", "w_down")},
-        },
-    }
+    """Seeded weights in the program's pytree, made in one jitted call: each
+    tensor of the reference's layout at its path."""
+    glob, groups = ref.layout(cfg)
+    tree: Dict[str, Any] = {}
+    for path, arr in weights.make_all(seed, glob, groups, cfg["torch_dtype"]).items():
+        *parents, leaf = path.split("/")
+        node = tree
+        for name in parents:
+            node = node.setdefault(name, {})
+        node[leaf] = arr
+    return tree
+
+
+def check_params(mc, params) -> None:
+    """Refuse weights whose tree, shapes or dtypes differ from the program's
+    own ``init`` for ``mc``."""
+    from repro.models import get_model
+
+    def leaves(tree):
+        return {jax.tree_util.keystr(p): (tuple(x.shape), str(x.dtype))
+                for p, x in jax.tree_util.tree_leaves_with_path(tree)}
+
+    got = leaves(params)
+    want = leaves(jax.eval_shape(lambda: get_model(mc).init(jax.random.PRNGKey(0), mc)))
+    if got != want:
+        diff = {k: (got.get(k), want.get(k)) for k in sorted(set(got) | set(want))
+                if got.get(k) != want.get(k)}
+        raise SystemExit(f"weights differ from the program's tree for {mc.name} "
+                         f"(path: (made, program)): {diff}")
 
 
 def seq_rungs(policy: str) -> List[int]:
@@ -85,6 +98,7 @@ class Cell:
 
         self.cfg, self.cell = cfg, cell
         self.mc = program_config(cfg)
+        check_params(self.mc, params)
         s = cell["server"]
         self.max_len = int(s["max_len"])
         self.srv = BatchedServer(

@@ -1,8 +1,14 @@
 """Arithmetic that per-layer metric readers share.
 
 Each reader in ``perfbench/metrics/<metric>.py`` takes the run's context
-(see ``run.py``) and returns a number, or None where the run gave it
-nothing to read; the harness then leaves the metric out.  Shares are in %.
+and returns a number, or None where the run gave it nothing to read; the
+harness then leaves the metric out.  Shares are in %.  The context
+(``run.run_cell``) holds ``res`` (the program's ``run()`` result),
+``counters`` (the window's differences of the program's counters),
+``work`` (``flops.window_work``), ``peak``, ``roofline_s``, ``in_flight_s``,
+``setup_s``, and in traced runs ``trace`` (``trace.reduce``) and ``spans``
+(``spans.reduce``: the device time by the program's scopes, idle by its
+spans); both are None in untraced runs.
 """
 from __future__ import annotations
 
@@ -56,3 +62,10 @@ def kernels_roofline(ctx: Dict) -> Optional[float]:
 def idle_share(ctx: Dict) -> Optional[float]:
     share = trace.idle_share(ctx["trace"]) if ctx.get("trace") else None
     return None if share is None else 100.0 * share
+
+
+def loop_idle_share(ctx: Dict) -> Optional[float]:
+    sp = ctx.get("spans")
+    if not sp or not sp["window_s"]:
+        return None
+    return 100.0 * sp["loop_idle_s"] / sp["window_s"]

@@ -1,14 +1,24 @@
 """Seeded weights, by name, made on the device.
 
-Each tensor of a reference's ``layout`` has its own key, folded from the run
-seed, the tensor's index in the layout and its layer, so the benchmark can
-make every layer at once for the program (one jitted call, in the type the
-weights are served in) and the reference can make one layer again after the
-window, bit for bit, without reading anything the program holds.
+A reference's ``layout(cfg)`` is ``(globals, groups)``: globals a list of
+``(name, shape, init, path)``, groups a list of ``(count, per-layer
+tensors)`` in layer order, each tensor again ``(name, shape, init,
+path)``.  ``path`` is the tensor's place in the program's pytree
+(``blocks/attn/wq``); a group's tensors are stacked over its layers there,
+and groups that name one path are joined along the layer axis in order.
+
+Each tensor has its own key, folded from the run seed, the tensor's index
+(globals first, then each group's tensors in turn) and its global layer
+index, so the benchmark can make every layer at once for the program (one
+jitted call, in the type the weights are served in) and the reference can
+make one layer again after the window, bit for bit, without reading
+anything the program holds.
 
 Inits: ``linear`` normal / sqrt(fan-in) and ``embed`` normal * 0.02, both in
-the configuration's dtype; ``norm`` 1 + 0.1 * normal in float32, the type
-the program keeps norm scales in.
+the configuration's dtype; ``linear_f32`` as ``linear`` in float32 (a
+router, kept so by the program); ``norm`` 1 + 0.1 * normal in float32, the
+type the program keeps norm scales in.  Fan-in is the second-to-last axis
+(an expert stack is ``(experts, in, out)``).
 """
 from __future__ import annotations
 
@@ -33,7 +43,9 @@ def _tensor(key, shape: Tuple[int, ...], init: str, dtype) -> jax.Array:
     if init == "embed":
         return (z * 0.02).astype(dtype)
     if init == "linear":
-        return (z * (1.0 / np.sqrt(shape[0]))).astype(dtype)
+        return (z * (1.0 / np.sqrt(shape[-2]))).astype(dtype)
+    if init == "linear_f32":
+        return z * (1.0 / np.sqrt(shape[-2]))
     raise ValueError(f"unknown init {init!r}")
 
 
@@ -41,54 +53,76 @@ def _key(base, tid: int, layer: int):
     return jax.random.fold_in(jax.random.fold_in(base, tid), layer)
 
 
-def make_all(seed: int, glob: List, per_layer: List, n_layers: int, dtype: str):
-    """``(globals {name: array}, layers {name: (n_layers, ...) array})`` in
-    one jitted call."""
+def extents(glob: List, groups: List) -> List[Tuple[int, int, int]]:
+    """(first tensor id, first layer, count) of each group."""
+    out, tid, first = [], len(glob), 0
+    for count, tensors in groups:
+        out.append((tid, first, int(count)))
+        tid, first = tid + len(tensors), first + int(count)
+    return out
+
+
+def n_layers(groups: List) -> int:
+    return sum(int(count) for count, _ in groups)
+
+
+def make_all(seed: int, glob: List, groups: List, dtype: str) -> Dict[str, jax.Array]:
+    """``{path: array}`` of every tensor, each group stacked over its layers
+    (``(count, ...)``, joined with the groups that share its path), in one
+    jitted call."""
     dt = jnp.dtype(dtype)
-    n_glob = len(glob)
 
     def build(base):
-        g = {name: _tensor(_key(base, i, 0), shape, init, dt)
-             for i, (name, shape, init) in enumerate(glob)}
+        out = {path: _tensor(_key(base, i, 0), shape, init, dt)
+               for i, (_, shape, init, path) in enumerate(glob)}
+        stacked: Dict[str, List[jax.Array]] = {}
+        for (tid, first, count), (_, tensors) in zip(extents(glob, groups), groups):
+            def one_layer(layer, tid=tid, tensors=tensors):
+                return {path: _tensor(_key(base, tid + i, layer), shape, init, dt)
+                        for i, (_, shape, init, path) in enumerate(tensors)}
 
-        def one_layer(layer):
-            return {name: _tensor(_key(base, n_glob + i, layer), shape, init, dt)
-                    for i, (name, shape, init) in enumerate(per_layer)}
-
-        return g, jax.vmap(one_layer)(jnp.arange(n_layers))
+            for path, arr in jax.vmap(one_layer)(first + jnp.arange(count)).items():
+                stacked.setdefault(path, []).append(arr)
+        out.update({path: arrs[0] if len(arrs) == 1 else jnp.concatenate(arrs)
+                    for path, arrs in stacked.items()})
+        return out
 
     return jax.jit(build)(base_key(seed))
 
 
-def layer_maker(glob: List, per_layer: List, dtype: str):
-    """``(seed, layer) -> {name: float32 array}``: one layer, as served, upcast."""
+def layer_maker(glob: List, groups: List, dtype: str):
+    """``(seed, layer) -> (group, {name: float32 array})``: one layer of its
+    group, as served, upcast."""
     dt = jnp.dtype(dtype)
-    n_glob = len(glob)
+    where = extents(glob, groups)
 
-    @jax.jit
-    def build(base, layer):
-        return {name: _tensor(_key(base, n_glob + i, layer), shape, init, dt)
-                .astype(jnp.float32)
-                for i, (name, shape, init) in enumerate(per_layer)}
+    def group_build(tid, tensors):
+        @jax.jit
+        def build(base, layer):
+            return {name: _tensor(_key(base, tid + i, layer), shape, init, dt)
+                    .astype(jnp.float32)
+                    for i, (name, shape, init, _) in enumerate(tensors)}
+        return build
 
-    return lambda seed, layer: build(base_key(seed), jnp.int32(layer))
+    builds = [group_build(tid, tensors) for (tid, _, _), (_, tensors) in zip(where, groups)]
+
+    def make(seed, layer):
+        g = next(k for k, (_, first, count) in enumerate(where) if layer < first + count)
+        return g, builds[g](base_key(seed), jnp.int32(layer))
+
+    return make
 
 
 def global_maker(glob: List, dtype: str):
-    """``(seed, names) -> {name: float32 array}`` for the global tensors."""
+    """``seed -> {name: float32 array}``: the global tensors, as served, upcast."""
     dt = jnp.dtype(dtype)
-    index: Dict[str, int] = {name: i for i, (name, _, _) in enumerate(glob)}
 
-    def build(seed, names):
+    def build(seed):
         base = base_key(seed)
-        out = {}
-        for name in names:
-            i = index[name]
-            _, shape, init = glob[i]
-            out[name] = jax.jit(
-                lambda b, i=i, shape=shape, init=init:
-                _tensor(_key(b, i, 0), shape, init, dt).astype(jnp.float32)
-            )(base)
-        return out
+        return {name: jax.jit(
+                    lambda b, i=i, shape=shape, init=init:
+                    _tensor(_key(b, i, 0), shape, init, dt).astype(jnp.float32)
+                )(base)
+                for i, (name, shape, init, _) in enumerate(glob)}
 
     return build

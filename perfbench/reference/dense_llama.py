@@ -13,39 +13,113 @@ layer is made again from the seed by :mod:`bench.weights`.
 matrix multiplication's operands rounded to float8 e4m3 (a scale per row of
 activations and per output column of weights, as an fp8 serving path would
 use), accumulated in float32.
+
+For the harness it also states the program's config fields it stands for
+(``program_fields``), each weight's place in the program's pytree
+(``layout``) and the work a token and a dispatch cost (``counted_work``).
 """
 from __future__ import annotations
 
+from dataclasses import dataclass
 from typing import Callable, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+Tensor = Tuple[str, Tuple[int, ...], str, str]
 
-def layout(cfg: Dict) -> Tuple[List[Tuple[str, Tuple[int, ...], str]],
-                               List[Tuple[str, Tuple[int, ...], str]]]:
-    """(global tensors, per-layer tensors) as (name, shape, init)."""
+
+def program_fields(cfg: Dict) -> Dict[str, object]:
+    """The program's ModelConfig fields this configuration stands for."""
+    return {
+        "d_model": cfg["hidden_size"], "d_ff": cfg["intermediate_size"],
+        "n_heads": cfg["num_attention_heads"], "n_kv_heads": cfg["num_key_value_heads"],
+        "vocab": cfg["vocab_size"], "rope_theta": float(cfg["rope_theta"]),
+        "tie_embeddings": bool(cfg["tie_word_embeddings"]), "ffn": "swiglu",
+        "norm": "rmsnorm", "family": "dense",
+    }
+
+
+def layout(cfg: Dict) -> Tuple[List[Tensor], List[Tuple[int, List[Tensor]]]]:
+    """(global tensors, [(layers, per-layer tensors)]) as (name, shape,
+    init, program path): one group of identical layers."""
     d, ff, V = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
     H, KVH = cfg["num_attention_heads"], cfg["num_key_value_heads"]
     hd = cfg.get("head_dim") or d // H
     glob = [
-        ("embed", (V, d), "embed"),
-        ("final_norm", (d,), "norm"),
-        ("lm_head", (d, V), "linear"),
+        ("embed", (V, d), "embed", "embed"),
+        ("final_norm", (d,), "norm", "final_norm/scale"),
+        ("lm_head", (d, V), "linear", "lm_head"),
     ]
     per_layer = [
-        ("attn_norm", (d,), "norm"),
-        ("wq", (d, H * hd), "linear"),
-        ("wk", (d, KVH * hd), "linear"),
-        ("wv", (d, KVH * hd), "linear"),
-        ("wo", (H * hd, d), "linear"),
-        ("ffn_norm", (d,), "norm"),
-        ("w_gate", (d, ff), "linear"),
-        ("w_up", (d, ff), "linear"),
-        ("w_down", (ff, d), "linear"),
+        ("attn_norm", (d,), "norm", "blocks/norm1/scale"),
+        ("wq", (d, H * hd), "linear", "blocks/attn/wq"),
+        ("wk", (d, KVH * hd), "linear", "blocks/attn/wk"),
+        ("wv", (d, KVH * hd), "linear", "blocks/attn/wv"),
+        ("wo", (H * hd, d), "linear", "blocks/attn/wo"),
+        ("ffn_norm", (d,), "norm", "blocks/norm2/scale"),
+        ("w_gate", (d, ff), "linear", "blocks/ffn/w_gate"),
+        ("w_up", (d, ff), "linear", "blocks/ffn/w_up"),
+        ("w_down", (ff, d), "linear", "blocks/ffn/w_down"),
     ]
-    return glob, per_layer
+    return glob, [(int(cfg["num_hidden_layers"]), per_layer)]
+
+
+@dataclass(frozen=True)
+class Dims:
+    """Counted work of the dense decoder (``bench.flops.Work``): q, k, v, o
+    and a SwiGLU feed-forward per layer, an untied LM head; K and V of every
+    layer per token; every weight read once per dispatch."""
+
+    layers: int
+    d: int
+    ff: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    vocab: int
+    bytes_per: int = 2
+
+    @classmethod
+    def of(cls, cfg: Dict) -> "Dims":
+        d, H = cfg["hidden_size"], cfg["num_attention_heads"]
+        return cls(layers=cfg["num_hidden_layers"], d=d, ff=cfg["intermediate_size"],
+                   heads=H, kv_heads=cfg["num_key_value_heads"],
+                   head_dim=cfg.get("head_dim") or d // H, vocab=cfg["vocab_size"],
+                   bytes_per={"bfloat16": 2, "float16": 2, "float32": 4}[cfg["torch_dtype"]])
+
+    @property
+    def matmul_params(self) -> int:
+        q = self.heads * self.head_dim
+        kv = self.kv_heads * self.head_dim
+        per_layer = self.d * q + 2 * self.d * kv + q * self.d + 3 * self.d * self.ff
+        return self.layers * per_layer + self.d * self.vocab
+
+    @property
+    def weight_bytes(self) -> int:
+        """Bytes of every weight: matmul weights and norm scales."""
+        return self.matmul_params * self.bytes_per + (2 * self.layers + 1) * self.d * 4
+
+    def dispatch_weight_bytes(self, tokens: float) -> int:
+        return self.weight_bytes
+
+    @property
+    def kv_bytes_per_token(self) -> int:
+        return 2 * self.layers * self.kv_heads * self.head_dim * self.bytes_per
+
+    @property
+    def embed_bytes_per_token(self) -> int:
+        return self.d * self.bytes_per
+
+    def attn_flops(self, keys: int) -> int:
+        return 4 * self.layers * self.heads * self.head_dim * keys
+
+    def token_flops(self, keys: int) -> int:
+        return 2 * self.matmul_params + self.attn_flops(keys)
+
+
+counted_work = Dims.of
 
 
 def _fp8(x: jax.Array, axis: int) -> jax.Array:
@@ -77,8 +151,9 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def make_layer_fn(cfg: Dict, precision: str = "float32") -> Callable:
-    """``(h (B, S, d) float32, layer weights) -> h`` for one block."""
+def make_layer_fns(cfg: Dict, precision: str = "float32") -> List[Callable]:
+    """``(h (B, S, d) float32, layer weights) -> h`` for one block, for the
+    layout's one group."""
     d = cfg["hidden_size"]
     H, KVH = cfg["num_attention_heads"], cfg["num_key_value_heads"]
     hd = cfg.get("head_dim") or d // H
@@ -106,11 +181,16 @@ def make_layer_fn(cfg: Dict, precision: str = "float32") -> Callable:
         x = _rms(h, w["ffn_norm"], eps)
         return h + mm(jax.nn.silu(mm(x, w["w_gate"])) * mm(x, w["w_up"]), w["w_down"])
 
-    return jax.jit(layer)
+    return [jax.jit(layer)]
+
+
+def embed(g: Dict, tokens: jax.Array) -> jax.Array:
+    """Token ids (B, S) -> their embedding rows (B, S, d)."""
+    return jnp.take(g["embed"], tokens, axis=0)
 
 
 def make_head_fn(cfg: Dict, precision: str = "float32") -> Callable:
-    """``(h (N, d) float32, final_norm, lm_head) -> logits (N, V)``."""
+    """``(h (N, d) float32, global weights) -> logits (N, V)``."""
     eps = float(cfg["rms_norm_eps"])
     mm = _matmul(precision)
-    return jax.jit(lambda h, norm, head: mm(_rms(h, norm, eps), head))
+    return jax.jit(lambda h, g: mm(_rms(h, g["final_norm"], eps), g["lm_head"]))

@@ -5,15 +5,18 @@
 Run from the root of a checkout.  The cell is an entry of ``BENCHMARK.json``;
 its configuration, traffic mix and server options are the files named after
 it (``perfbench/configs/<config>.json``, ``perfbench/traffic/<traffic>.json``,
-``perfbench/cells/<cell>.json``), and each per-layer metric is read by
-``perfbench/metrics/<metric>.py``.  A run makes its weights and requests from
-``--seed``, warms every program the traffic can reach (set-up), serves the
-requests in an open loop for ``--seconds`` plus the drain, reads peak device
-memory, frees the program and compares a sample of the served tokens with
-the plain reference.  The last line of standard output is one JSON object;
-the last lines of standard error are the numbers compared, each with its
-limit.  Without a TPU, or with fewer chips than the cell asks for, it exits 1
-and prints no result.
+``perfbench/cells/<cell>.json``); the configuration names its plain
+reference module (``perfbench/reference/<reference>.py``), which also
+states the program's config, the weights' layout and the counted work; and
+each per-layer metric is read by ``perfbench/metrics/<metric>.py``.  A run
+makes its weights and requests from ``--seed``, warms every program the
+traffic can reach (set-up), serves the requests in an open loop for
+``--seconds`` plus the drain, reads peak device memory, frees the program
+and compares a sample of the served tokens with the plain reference.  The
+last line of standard output is one JSON object; the last lines of
+standard error are the numbers compared, each with its limit.  Without a
+TPU, or with fewer chips than the cell asks for, it exits 1 and prints no
+result.
 """
 from __future__ import annotations
 
@@ -152,7 +155,7 @@ def run_cell(spec: Dict, seed: int, seconds: float, trace: bool, *,
     """
     import jax
 
-    from bench import correct, flops, program
+    from bench import correct, flops, program, spans
     from bench import trace as tr
     from bench import traffic as gen
 
@@ -208,27 +211,37 @@ def run_cell(spec: Dict, seed: int, seconds: float, trace: bool, *,
     compiles["on"] = False
     gc.callbacks.remove(on_gc)
     flight = in_flight(res)
-    reduction = None
+    reduction = split = None
+    trace_cost: Dict[str, float] = {}  # seconds after the window, by step
     if trace:
+        t = time.perf_counter()
         jax.profiler.stop_trace()
-        reduction = tr.reduce(tr.find_xplane(log_dir), SPANS,
-                              quiet=quiet(flight, res["wall_s"]))
+        trace_cost["stop_s"] = time.perf_counter() - t
+        xplane, idle = tr.find_xplane(log_dir), quiet(flight, res["wall_s"])
+        t = time.perf_counter()
+        reduction = tr.reduce(xplane, SPANS, quiet=idle)
+        trace_cost["trace_reduce_s"] = time.perf_counter() - t
+        t = time.perf_counter()
+        split = spans.reduce(xplane, quiet=idle)
+        trace_cost["spans_reduce_s"] = time.perf_counter() - t
         import shutil
 
         shutil.rmtree(log_dir, ignore_errors=True)
     peak = program.peak_bytes(dev)
     e2e = end_to_end(res, setup_s, peak)
 
-    # per-layer context: counters, counted work, trace
-    dims = flops.Dims.of(cfg)
+    # per-layer context: counters, counted work, trace and the program's
+    # span and scope split of it, set-up time
     reqs = res["requests"]
     per_req = [(len(r.prompt), res["skips"][r.rid], len(res["results"][r.rid]["tokens"]))
                for r in reqs if "error" not in res["results"][r.rid]]
-    work = flops.window_work(dims, per_req, res["prefill_dispatches"], res["decode_dispatches"])
+    work = flops.window_work(ref.counted_work(cfg), per_req, res["prefill_dispatches"],
+                             res["decode_dispatches"])
     pk = flops.peaks(dev.device_kind) if require_chip else None
     in_flight_s = sum(b - a for a, b in flight)
     ctx = {"res": res, "counters": res["window_counters"], "trace": reduction,
-           "work": work, "peak": pk, "in_flight_s": in_flight_s,
+           "spans": split, "setup_s": setup_s, "work": work, "peak": pk,
+           "in_flight_s": in_flight_s,
            "roofline_s": flops.roofline_seconds(work, pk) if pk else None}
 
     failed = sum("error" in res["results"][r.rid] for r in reqs)
@@ -236,12 +249,15 @@ def run_cell(spec: Dict, seed: int, seconds: float, trace: bool, *,
                                 "prefill_dispatches", "swaps", "resizes", "deferrals",
                                 "compiles", "kv_peak_pages_in_use", "kv_pages_capacity")}
     diag.update(window_compiles=compiles["window"], window_compiled=compiles["names"][:10],
-                window_full_gc_s=full_gc,
+                window_full_gc_s=full_gc, trace_cost=trace_cost,
                 counters=res["window_counters"],
                 prefilled_by_count=sum(p - s for p, s, _ in per_req), in_flight_s=in_flight_s,
                 work=work, e2e=e2e)
     if reduction:
         diag["trace"] = {k: reduction[k] for k in ("busy_s", "window_s", "devices")}
+    if split:
+        diag["spans"] = {k: split[k] for k in ("scope_s", "idle_by_span", "loop_idle_s",
+                                               "idle_covered_share", "gc_count")}
 
     # free the program before the reference runs
     cell.close()

@@ -89,17 +89,17 @@ def test_layer_weights_match_the_weights_served():
 
     cfg = spec()["config"]
     ref = correct.load_reference(cfg["reference"])
-    glob, per_layer = ref.layout(cfg)
-    g, layers = weights.make_all(SEED, glob, per_layer, cfg["num_hidden_layers"],
-                                 cfg["torch_dtype"])
-    one = weights.layer_maker(glob, per_layer, cfg["torch_dtype"])
+    glob, groups = ref.layout(cfg)
+    served = weights.make_all(SEED, glob, groups, cfg["torch_dtype"])
+    one = weights.layer_maker(glob, groups, cfg["torch_dtype"])
     for layer in range(cfg["num_hidden_layers"]):
-        w = one(SEED, layer)
-        for name, _, _ in per_layer:
+        group, w = one(SEED, layer)
+        for name, _, _, path in groups[group][1]:
             np.testing.assert_array_equal(np.asarray(w[name]),
-                                          np.asarray(layers[name][layer], np.float32))
-    head = weights.global_maker(glob, cfg["torch_dtype"])(SEED, ["lm_head"])["lm_head"]
-    np.testing.assert_array_equal(np.asarray(head), np.asarray(g["lm_head"], np.float32))
+                                          np.asarray(served[path][layer], np.float32))
+    g = weights.global_maker(glob, cfg["torch_dtype"])(SEED)
+    for name, _, _, path in glob:
+        np.testing.assert_array_equal(np.asarray(g[name]), np.asarray(served[path], np.float32))
     assert jax.devices()[0].platform == "cpu"
 
 

@@ -184,3 +184,23 @@ def test_self_times(ops, lo, want):
     got = spans.self_times([(f"op{k}", "", s * US, e * US) for k, (s, e) in enumerate(ops)],
                            lo * US, 20 * US)
     assert [t / US for _, _, t in got] == pytest.approx(want)
+
+
+def test_loop_idle_share_reads_the_span_split(red):
+    from bench import readers
+
+    assert readers.loop_idle_share({"spans": red}) == pytest.approx(100 * 6.9 / 20)
+    assert readers.loop_idle_share({"spans": None}) is None
+
+
+def test_a_traced_run_hands_readers_the_span_split():
+    """``run_cell`` reduces its trace against the program's names too; the
+    CPU has no device plane, so there is nothing to split and the reader
+    returns nothing."""
+    from bench import readers
+
+    run = tiny.R.run_cell(tiny.spec(), 2**31 + 99, 2.0, True, require_chip=False)
+    ctx = run["ctx"]
+    assert ctx["setup_s"] > 0
+    assert ctx["spans"]["devices"] == 0 and ctx["spans"]["window_s"] == 0.0
+    assert readers.loop_idle_share(ctx) is None
