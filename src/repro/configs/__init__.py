@@ -1,6 +1,6 @@
 """Architecture registry — the ``--arch <id>`` lookup.
 
-Ten assigned architectures (one module each, exact published configs) +
+Eleven architectures (one module each, exact published configs) +
 ``forge-125m`` (a GPT-2-class config for the paper-scale benchmarks and
 the end-to-end training example).
 """
@@ -11,6 +11,7 @@ from typing import Dict, List
 from .base import ModelConfig
 from . import (
     deepseek_7b,
+    deepseek_v2_lite,
     kimi_k2_1t_a32b,
     phi3_mini_38b,
     phi35_moe_42b_a66b,
@@ -38,6 +39,7 @@ _MODULES = [
     qwen15_32b,
     phi3_mini_38b,
     deepseek_7b,
+    deepseek_v2_lite,
     qwen25_14b,
     recurrentgemma_2b,
     xlstm_350m,

@@ -31,12 +31,35 @@ class ModelConfig:
     tie_embeddings: bool = False
     rope_theta: float = 10_000.0
 
-    # MoE
+    # MoE: a router over ``n_experts`` routed experts picks ``top_k`` per
+    # token (softmax over all of them, greedy top-k); this program holds
+    # the first ``experts_held`` of them (0: all) — one chip's share under
+    # expert parallelism (``moe.moe_ffn``'s ``first`` names another rank's)
     n_experts: int = 0
     top_k: int = 0
-    capacity_factor: float = 1.25
+    experts_held: int = 0
+    norm_topk_prob: bool = True  # renormalise the k picked weights
+    routed_scaling_factor: float = 1.0
     shared_experts: int = 0
     shared_d_ff: int = 0
+    # leading dense layers (SwiGLU of width dense_d_ff) before the MoE stack
+    first_dense_layers: int = 0
+    dense_d_ff: int = 0
+
+    # latent attention (MLA) when kv_lora_rank > 0: per token one latent
+    # row of kv_lora_rank + qk_rope_head_dim values is cached
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
+    # YaRN rope scaling when yarn_factor > 0
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # hybrid (RecurrentGemma): block pattern unit, tiled over n_layers
     block_pattern: Tuple[str, ...] = ()  # e.g. ("rec", "rec", "attn")
@@ -77,6 +100,24 @@ class ModelConfig:
     def groups(self) -> int:
         return self.n_heads // max(self.n_kv_heads, 1)
 
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def n_held(self) -> int:
+        """Routed experts this program holds."""
+        return self.experts_held or self.n_experts
+
+    def attn_param_count(self) -> int:
+        d, H = self.d_model, self.n_heads
+        if self.mla:
+            r, rope = self.kv_lora_rank, self.qk_rope_head_dim
+            return (d * H * (self.qk_nope_head_dim + rope) + d * (r + rope) + r
+                    + r * H * (self.qk_nope_head_dim + self.v_head_dim)
+                    + H * self.v_head_dim * d)
+        return d * self.head_dim_ * (H * 2 + self.n_kv_heads * 2)
+
     def with_(self, **kw) -> "ModelConfig":
         return replace(self, **kw)
 
@@ -84,13 +125,13 @@ class ModelConfig:
 
     def param_count(self) -> int:
         d, hd = self.d_model, self.head_dim_
-        attn = d * hd * (self.n_heads * 2 + self.n_kv_heads * 2)
+        attn = self.attn_param_count()
         if self.ffn == "swiglu":
             ffn = 3 * d * self.d_ff
         else:
             ffn = 2 * d * self.d_ff
         if self.family == "moe":
-            ffn = self.n_experts * 3 * d * self.d_ff + d * self.n_experts
+            ffn = self.n_held * 3 * d * self.d_ff + d * self.n_experts
             if self.shared_experts:
                 ffn += 3 * d * (self.shared_d_ff or self.d_ff * self.shared_experts)
         per_layer = attn + ffn + 2 * d
@@ -118,7 +159,9 @@ class ModelConfig:
             n_dec = self.n_dec_layers or self.n_layers
             body = n_enc * per_layer + n_dec * (per_layer + attn + d)
         else:
-            body = self.n_layers * per_layer
+            F = self.first_dense_layers
+            dense = attn + 3 * d * self.dense_d_ff + 2 * d
+            body = (self.n_layers - F) * per_layer + F * dense
 
         emb = self.vocab * d
         head = 0 if self.tie_embeddings else self.vocab * d
@@ -129,9 +172,10 @@ class ModelConfig:
         if self.family != "moe":
             return self.param_count()
         d = self.d_model
-        dense_ffn = self.n_experts * 3 * d * self.d_ff
+        dense_ffn = self.n_held * 3 * d * self.d_ff
         active_ffn = self.top_k * 3 * d * self.d_ff
         if self.shared_experts:
             active_ffn += 3 * d * (self.shared_d_ff or self.d_ff * self.shared_experts)
             dense_ffn += 3 * d * (self.shared_d_ff or self.d_ff * self.shared_experts)
-        return self.param_count() - self.n_layers * (dense_ffn - active_ffn)
+        n_moe = self.n_layers - self.first_dense_layers
+        return self.param_count() - n_moe * (dense_ffn - active_ffn)

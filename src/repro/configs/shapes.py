@@ -107,7 +107,11 @@ def cache_specs(cfg: ModelConfig, batch: int, max_len: int):
     """ShapeDtypeStruct pytree matching each family's ``init_cache``."""
     dt = jnp.dtype(cfg.dtype)
     hd = cfg.head_dim_
-    if cfg.family in ("dense", "moe", "vlm"):
+    if cfg.family in ("dense", "moe"):
+        from ..models import transformer
+
+        return jax.eval_shape(lambda: transformer.init_cache(cfg, batch, max_len))
+    if cfg.family == "vlm":
         shape = (cfg.n_layers, batch, cfg.n_kv_heads, max_len, hd)
         return {"k": sds(shape, dt), "v": sds(shape, dt)}
     if cfg.family == "encdec":

@@ -5,9 +5,10 @@ cache per slot — every admission paid ``max_len`` rows of memory up
 front and slot swap-in was an O(cache-copy) row gather.  This module
 extends the paper's explicit buffer-management philosophy (Phase-4
 liveness + linear-scan allocation over IR registers) to the serving
-layer: the KV cache becomes a fixed page store (``kv_pages:
-[num_pages, n_kv_heads, page_size, head_dim]`` per layer) indexed by a
-per-slot int32 page table, and page lifetime is managed *explicitly*
+layer: the KV cache becomes a fixed page store (token-major rows,
+``[n_layers, num_pages, page_size, row]``: K and V of every head, or an
+MLA model's latent row; see ``models.transformer.init_paged_cache``)
+indexed by a per-slot int32 page table, and page lifetime is managed *explicitly*
 by the host — alloc at admission, refcount while referenced, free at
 retirement — instead of opaquely by bucket residency.
 

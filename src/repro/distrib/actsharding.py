@@ -66,11 +66,6 @@ class ActivationPolicy:
             pat = (dp, None, tp)
         elif kind == "moe_tokens":  # (T, D) flat token stream
             pat = (dp, None)
-        elif kind == "moe_dispatch":  # (E, C, D/F) expert-major buffers
-            # GShard layout: experts over model (EP) AND capacity over the
-            # data axes, so dispatch/combine lower to all-to-all instead
-            # of replicated scatters
-            pat = (tp, dp, None)
         else:
             return None
         if len(pat) != nd:

@@ -125,9 +125,9 @@ class BatchedServer:
     prompt block folds into the recurrent state via an associative scan
     (per-row ``length`` bounds each row's scan, since state consumes
     every chunk token).  Only families where the algorithm couples
-    tokens across the block (MoE capacity routing) fall back to the
-    sequential decode-step loop automatically, as do prompts whose
-    sequence bucket would not fit ``max_len``.  The prefill front takes
+    tokens across the block fall back to the sequential decode-step
+    loop automatically, as do prompts whose sequence bucket would not
+    fit ``max_len``.  The prefill front takes
     a ``slot_mask`` too: the slot scheduler prefills a queued prompt
     into a finished slot's KV rows while every other slot's cache stays
     bitwise untouched (write-inert masking, DESIGN.md §Continuous
@@ -186,7 +186,7 @@ class BatchedServer:
         #: the decode multi-program front (mode=forge); built once
         self.bucketed = None
         #: the 2-D (batch × sequence) whole-prompt prefill front; None
-        #: for families without a batched prefill (MoE routing)
+        #: for families without a batched prefill
         self.prefill_bucketed = None
         #: per-leaf cache batch axes (set with the fronts; the slot
         #: scheduler's bucket-resize row gather reads it)
@@ -204,10 +204,11 @@ class BatchedServer:
         self.kv_pages = kv_pages
         self.page_pool = None
         self.prefix_tree = None
-        #: server-resident {k_pages, v_pages} store (no batch axis),
-        #: each leaf (n_layers, num_pages, page_size, KVH * head_dim) in
-        #: token-major rows; every slot reads/writes it through its
-        #: page-table row, and the server never looks inside it
+        #: server-resident page store (no batch axis): every entry of the
+        #: model's ``init_paged_cache`` but the page table, each leaf
+        #: (n_layers, num_pages, page_size, row) in token-major rows;
+        #: every slot reads/writes it through its page-table row, and the
+        #: server never looks inside it
         self.page_store = None
         self.max_pages_per_slot = 0
         if self.paged:
@@ -356,6 +357,7 @@ class BatchedServer:
             dealias_tree,
             make_paged_prefill_step,
             make_paged_serve_step,
+            paged_store,
         )
 
         ps = self.kv_page_size
@@ -368,9 +370,7 @@ class BatchedServer:
         full = self.model.init_paged_cache(
             self.cfg, 1, self.max_len, num_pages=num_pages, page_size=ps
         )
-        self.page_store = dealias_tree(
-            {"k_pages": full["k_pages"], "v_pages": full["v_pages"]}
-        )
+        self.page_store = dealias_tree(paged_store(full))
         self.cache_axes = None  # no batch-polymorphic cache rows exist
         compiler = ForgeCompiler(PipelineConfig(backend=self.backend),
                                  cache=self.compile_cache)
@@ -379,17 +379,18 @@ class BatchedServer:
             pstep = make_paged_prefill_step(self.cfg)
             if pstep is not None:
                 # (params, store, page_table(B,MP), tokens(B,S),
-                #  pos(B,), mask(B,)) — per-row pos lets prefix-hit rows
-                # anchor their chunk at the skip offset in the same
-                # dispatch as cold rows
+                #  pos(B,), mask(B,), last(B,)) -> ((B, vocab) logits
+                # of each row's last real column, store) — per-row pos
+                # lets prefix-hit rows anchor their chunk at the skip
+                # offset in the same dispatch as cold rows
                 prefill_front = compiler.compile_bucketed(
                     pstep,
                     axes=(
-                        PolyAxis(in_axes=(None, None, 0, 0, 0, 0),
+                        PolyAxis(in_axes=(None, None, 0, 0, 0, 0, 0),
                                  out_axes=(0, None),
                                  policy=self.bucket_policy, label="B"),
-                        PolyAxis(in_axes=(None, None, None, 1, None, None),
-                                 out_axes=(1, None),
+                        PolyAxis(in_axes=(None, None, None, 1, None, None, None),
+                                 out_axes=(None, None),
                                  policy=self.seq_bucket_policy, label="S"),
                     ),
                     async_compile=self.async_compile,
@@ -469,7 +470,8 @@ class BatchedServer:
                     jnp.zeros((extent, MP), jnp.int32),
                     jnp.zeros((extent, s_ext), jnp.int32),
                     jnp.zeros((extent,), jnp.int32),
-                    jnp.zeros((extent,), bool))
+                    jnp.zeros((extent,), bool),
+                    jnp.zeros((extent,), jnp.int32))
         cache = self._build_cache(extent)
         tokens = jnp.zeros((extent, s_ext), jnp.int32)
         return (self.params, cache) + self._prefill_args(extent, tokens, 0)
@@ -745,7 +747,8 @@ class BatchedServer:
                     pargs = (jnp.zeros((extent, MP), jnp.int32),
                              jnp.zeros((extent, s_ext), jnp.int32),
                              jnp.zeros((extent,), jnp.int32),
-                             jnp.zeros((extent,), bool))
+                             jnp.zeros((extent,), bool),
+                             jnp.zeros((extent,), jnp.int32))
                     pmod, pkey, _ = self.prefill_bucketed.program_for(
                         self.params, store, *pargs
                     )
@@ -1464,6 +1467,9 @@ class SlotScheduler:
         slots: List[Optional[_Slot]] = []
         extent = 0
         cache = srv.page_store if paged else None
+        #: the store's expert counter at the start (an MoE config's, see
+        #: models/transformer.py EXPERT_LOAD): the run reports its growth
+        load0 = cache.get("expert_load") if isinstance(cache, dict) else None
         mod = key = None
         cur_tok = np.zeros((0, 1), np.int32)
         cur_pos = np.zeros((0,), np.int32)
@@ -2205,6 +2211,12 @@ class SlotScheduler:
                 pages_reused=ps_.pages_reused,
                 pages_reclaimed=ps_.pages_reclaimed,
             )
+            if load0 is not None:
+                #: rows (decode, prefill); columns (picks that reached a
+                #: held expert, held experts, held experts picked), summed
+                #: over MoE layers and dispatches: read once, after the run
+                out["expert_load"] = (np.asarray(cache["expert_load"])
+                                      - np.asarray(load0)).tolist()
         return out
 
     def _admit(self, admitted: List[int], slots: List[Optional[_Slot]],
@@ -2382,6 +2394,7 @@ class SlotScheduler:
         tokens = np.zeros((extent, s_ext), np.int32)
         mask = np.zeros((extent,), bool)
         pos_np = np.zeros((extent,), np.int32)
+        last = np.zeros((extent,), np.int32)
         for i, L in zip(live, Ls):
             s = slots[i]
             suffix = np.asarray(s.req.prompt[s.skip:], np.int32)
@@ -2389,8 +2402,9 @@ class SlotScheduler:
             tokens[i, L:] = suffix[-1]  # edge pad
             mask[i] = True
             pos_np[i] = s.skip
+            last[i] = L - 1
         pargs = (jnp.asarray(pt_host), jnp.asarray(tokens),
-                 jnp.asarray(pos_np), jnp.asarray(mask))
+                 jnp.asarray(pos_np), jnp.asarray(mask), jnp.asarray(last))
         try:
             with span("serve.prefill"):
                 pmod, pkey, _ = srv.prefill_bucketed.program_for(
@@ -2421,17 +2435,9 @@ class SlotScheduler:
         )
         self.metrics["prefill_dispatches"] += 1
         pool.stats.tokens_prefilled += sum(Ls)
-        # device-side gather of each row's last-real-suffix-column
-        # argmax, padded to a fixed (extent,) shape so the jitted gather
-        # depends only on the bucket cell, never on how many rows this
-        # particular wave admitted (fault-requeued retries reuse it)
-        rows_p = np.zeros((extent,), np.int32)
-        cols_p = np.zeros((extent,), np.int32)
-        rows_p[: len(live)] = live
-        cols_p[: len(live)] = [L - 1 for L in Ls]
-        firsts = np.asarray(
-            guarded_argmax(logits[jnp.asarray(rows_p), jnp.asarray(cols_p)])
-        ).astype(np.int32)[: len(live)]
+        # the step gave each row's last-real-suffix-column logits: only
+        # the argmax of every row crosses to host
+        firsts = np.asarray(guarded_argmax(logits)).astype(np.int32)[live]
         for i, first in zip(live, firsts):
             s = slots[i]
             P = len(s.req.prompt)

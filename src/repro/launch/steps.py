@@ -108,12 +108,7 @@ def make_loss_fn(cfg: ModelConfig) -> Callable:
 
     def loss_fn(params, batch):
         logits = fwd(params, batch)
-        loss = losses.cross_entropy(logits, batch["labels"])
-        if cfg.family == "moe":
-            # Switch-style aux loss keeps experts balanced; computed on the
-            # first block's router over the embedded tokens
-            pass  # aux loss handled inside moe blocks in a later revision
-        return loss
+        return losses.cross_entropy(logits, batch["labels"])
 
     return loss_fn
 
@@ -232,7 +227,7 @@ def supports_batched_prefill(cfg: ModelConfig) -> bool:
     write) and, via the chunked state scan, the recurrent families
     (rg-lru associative scan, mLSTM (C, n, m) scan, sLSTM in-program
     ``lax.scan``).  False only where the algorithm itself couples
-    tokens across the block (MoE capacity routing).
+    tokens across the block.
     """
     return get_model(cfg).prefill_step is not None
 
@@ -248,8 +243,8 @@ def make_slot_prefill_step(cfg: ModelConfig):
     rows of the *masked* slots only — every other slot's cache survives
     bitwise, so a queued prompt can be prefilled into a finished slot
     while its neighbours are mid-generation.  None for families without
-    a batched prefill (MoE capacity routing) — those swap in through
-    masked decode-step replay instead.
+    a batched prefill — those swap in through masked decode-step replay
+    instead.
     """
     model = get_model(cfg)
     if not supports_batched_prefill(cfg) or not supports_slot_decode(cfg):
@@ -277,9 +272,8 @@ def make_batched_prefill_step(cfg: ModelConfig):
     cache)``: one forward pass folds the whole prompt block into the
     cache — causal chunk write for KV families, chunked state scan for
     the recurrent families.  Returns None only where a whole-block pass
-    cannot reproduce sequential decode (MoE capacity routing couples
-    tokens across the block) — the server then prefills sequentially
-    through ``decode_step``.
+    cannot reproduce sequential decode — the server then prefills
+    sequentially through ``decode_step``.
     """
     model = get_model(cfg)
     if not supports_batched_prefill(cfg):
@@ -303,13 +297,20 @@ def supports_paged_decode(cfg: ModelConfig) -> bool:
     )
 
 
+def paged_store(cache):
+    """The store of a paged cache: every entry but its ``page_table`` (K and
+    V pages, or an MLA config's latent pages, and any counter the model
+    keeps beside them)."""
+    return {k: v for k, v in cache.items() if k != "page_table"}
+
+
 def make_paged_serve_step(cfg: ModelConfig) -> Callable:
     """Slot-level greedy decode against the paged KV pool.
 
     ``(params, store, page_table(B, MP), token(B, 1), pos(B,),
     slot_mask(B,)) -> (next_tok(B, 1), new_store)``: same contract as
     :func:`make_slot_serve_step`, but the per-slot KV rows live behind a
-    page table into a shared page pool (``store`` = {k_pages, v_pages}).
+    page table into a shared page pool (``store``: :func:`paged_store`).
     The table is read-only here — allocation happens host-side in the
     scheduler — so swap-in/resize is a table edit, never a KV copy.
     """
@@ -324,9 +325,7 @@ def make_paged_serve_step(cfg: ModelConfig) -> Callable:
         )
         with jax.named_scope("logits"):
             next_tok = guarded_argmax(logits[:, -1, :])
-        new_store = {"k_pages": new_cache["k_pages"],
-                     "v_pages": new_cache["v_pages"]}
-        return next_tok[:, None], new_store
+        return next_tok[:, None], paged_store(new_cache)
 
     return paged_step
 
@@ -335,11 +334,13 @@ def make_paged_prefill_step(cfg: ModelConfig):
     """Slot-masked whole-prompt prefill into the paged KV pool.
 
     ``(params, store, page_table(B, MP), tokens(B, S), pos(B,),
-    slot_mask(B,)) -> ((B, S, vocab) logits, new_store)``.  ``pos`` is
-    per-row: a row whose leading pages were matched in the prefix tree
+    slot_mask(B,), last(B,)) -> ((B, vocab) logits, new_store)``.  ``pos``
+    is per-row: a row whose leading pages were matched in the prefix tree
     anchors its chunk at the skip offset, so prefix-hit and cold rows
-    prefill in the same dispatch.  None for families without a batched
-    prefill (MoE capacity routing).
+    prefill in the same dispatch.  The logits are those of each row's
+    column ``last`` (its last real prompt token) alone: the padded block's
+    other columns never reach the head.  None for families without a
+    batched prefill.
     """
     if not supports_paged_decode(cfg):
         return None
@@ -347,14 +348,12 @@ def make_paged_prefill_step(cfg: ModelConfig):
     if model.paged_prefill_step is None:
         return None
 
-    def paged_prefill(params, store, page_table, tokens, pos, slot_mask):
+    def paged_prefill(params, store, page_table, tokens, pos, slot_mask, last):
         cache = dict(store, page_table=page_table)
         logits, new_cache = model.paged_prefill_step(
-            params, cache, tokens, pos, cfg, slot_mask=slot_mask
+            params, cache, tokens, pos, cfg, slot_mask=slot_mask, last=last
         )
-        new_store = {"k_pages": new_cache["k_pages"],
-                     "v_pages": new_cache["v_pages"]}
-        return logits, new_store
+        return logits[:, 0], paged_store(new_cache)
 
     return paged_prefill
 

@@ -36,7 +36,7 @@ class ModelAPI:
     #: -> ((B,S,V) logits, cache); recurrent families fold the chunk
     #: into state via an associative scan (see prefill_takes_length);
     #: None only when a whole-block pass cannot reproduce sequential
-    #: decode (MoE capacity routing) — those prefill sequentially
+    #: decode — those prefill sequentially
     prefill_step: Optional[Callable]
     init_cache: Optional[Callable]
     module: Any
@@ -74,8 +74,8 @@ def get_model(cfg: ModelConfig) -> ModelAPI:
     else:
         raise ValueError(f"unknown family {cfg.family!r}")
     # a family module owns the knowledge of when a whole-block prefill
-    # pass reproduces sequential decode (e.g. transformer says no for
-    # MoE capacity routing); the registry stays family-agnostic
+    # pass reproduces sequential decode (``supports_batched_prefill``);
+    # the registry stays family-agnostic
     prefill = getattr(m, "prefill_step", None)
     supports = getattr(m, "supports_batched_prefill", None)
     if prefill is not None and supports is not None and not supports(cfg):

@@ -365,6 +365,124 @@ def attention(
     return constrain(out, "tokens"), new_cache
 
 
+# --------------------------------------------------------------------------
+# multi-head latent attention (MLA, DeepSeek-V2)
+# --------------------------------------------------------------------------
+
+
+def mla_init(key, d_model: int, n_heads: int, rank: int, nope: int, rope: int,
+             v_dim: int, *, dtype=jnp.bfloat16) -> Params:
+    """Published layout: ``wq`` (d, H·(nope+rope)); ``wkv_a`` (d, rank+rope),
+    the latent and the shared rope key; ``kv_norm`` over the latent;
+    ``wkv_b`` (rank, H·(nope+v)), each head's key and value up-projection;
+    ``wo`` (H·v, d)."""
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": L.dense_init(ks[0], d_model, n_heads * (nope + rope), dtype),
+        "wkv_a": L.dense_init(ks[1], d_model, rank + rope, dtype),
+        "kv_norm": L.norm_init(rank),
+        "wkv_b": L.dense_init(ks[2], rank, n_heads * (nope + v_dim), dtype),
+        "wo": L.dense_init(ks[3], n_heads * v_dim, d_model, dtype),
+    }
+
+
+def _latent_attend(q_nope: jax.Array, q_rope: jax.Array, rows: jax.Array,
+                   w_ukv: jax.Array, scale: float, mask) -> jax.Array:
+    """Absorbed attention over latent rows.
+
+    q_nope (B, H, S, nope) and q_rope (B, H, S, rope) post-rope; rows
+    (B, T, rank + rope): each key's normed latent, then its rotated rope
+    key; w_ukv (rank, H, nope + v): ``wkv_b`` split by head.  The query is
+    mapped into latent space by W_UK, scored against the whole row (latent
+    and rope key in one product), and the latent sum is mapped out by
+    W_UV: no per-head key or value is formed.  ``mask`` is additive
+    (1|B, 1, S, T) or a function of the scores."""
+    nope = q_nope.shape[-1]
+    rank = rows.shape[-1] - q_rope.shape[-1]
+    B, H, S, _ = q_nope.shape
+    with jax.named_scope("mla.attend"):
+        w_uk, w_uv = w_ukv[..., :nope], w_ukv[..., nope:]
+        q_lat = jnp.einsum("bhsn,rhn->bhsr", q_nope, w_uk,
+                           preferred_element_type=jnp.float32).astype(rows.dtype)
+        q = jnp.concatenate([q_lat, q_rope.astype(rows.dtype)], axis=-1)
+        s = jnp.einsum("bhsc,btc->bhst", q, rows,
+                       preferred_element_type=jnp.float32) * scale
+        s = mask(s) if callable(mask) else s + mask
+        p = jax.nn.softmax(s, axis=-1).astype(rows.dtype)
+        o = jnp.einsum("bhst,btr->bhsr", p, rows[..., :rank],
+                       preferred_element_type=jnp.float32).astype(rows.dtype)
+        o = jnp.einsum("bhsr,rhv->bshv", o, w_uv,
+                       preferred_element_type=jnp.float32).astype(rows.dtype)
+    return o.reshape(B, S, -1)
+
+
+def mla_attention(
+    x: jax.Array,
+    p: Params,
+    *,
+    n_heads: int,
+    nope: int,
+    rope_cos: jax.Array,
+    rope_sin: jax.Array,
+    scale: float,
+    cache: Optional[Dict[str, jax.Array]] = None,
+    cache_pos: Optional[jax.Array] = None,
+    kv_layer: Any = 0,
+    write_mask: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+    """MLA sub-layer.  Returns (out, updated cache).
+
+    The cache holds one row per token, the normed latent followed by the
+    rotated rope key: ``{"kv": (B, T, rank + rope)}`` for one layer of a
+    contiguous cache, or the paged store ``{"kv_pages": (L, NP, ps, rank +
+    rope), "page_table"}``, written in place like the MHA store (trash
+    page and slot mask as in :func:`_write_tokens`).  Without a cache the
+    rows of ``x`` itself are attended causally."""
+    B, S, _ = x.shape
+    rank = p["wkv_b"].shape[0]
+    q = L.linear(x, p["wq"]).reshape(B, S, n_heads, -1).transpose(0, 2, 1, 3)
+    q_nope = q[..., :nope]
+    q_rope = L.apply_rope(q[..., nope:], rope_cos, rope_sin)
+    kv = L.linear(x, p["wkv_a"])
+    lat = L.rms_norm(kv[..., :rank], p["kv_norm"]["scale"])
+    k_rope = L.apply_rope(kv[:, None, :, rank:], rope_cos, rope_sin)[:, 0]
+    new = jnp.concatenate([lat, k_rope], axis=-1)  # (B, S, rank + rope)
+    w_ukv = p["wkv_b"].reshape(rank, n_heads, -1)
+
+    new_cache = None
+    if cache is None:
+        rows = new
+        mask = lambda s: L.causal_where(s, S, S)  # noqa: E731
+    else:
+        pos = jnp.asarray(cache_pos, jnp.int32)
+        if "kv_pages" in cache:
+            pt = cache["page_table"].astype(jnp.int32)
+            layer = jnp.asarray(kv_layer, jnp.int32)
+            pos_row = jnp.broadcast_to(pos, (B,)) if pos.ndim == 0 else pos
+            with jax.named_scope("kv.write"):
+                store = _write_tokens(cache["kv_pages"], new[:, None], layer, pt,
+                                      pos_row, write_mask)
+            with jax.named_scope("kv.gather"):
+                rows = _gather_rows(store, layer, pt, 1, S)[:, 0]
+            new_cache = {"kv_pages": store}
+        else:
+            rows = cache["kv"]
+            if pos.ndim == 1:
+                if S != 1:
+                    raise NotImplementedError(
+                        "per-row cache positions require single-token steps")
+                idx = lax.broadcasted_iota(jnp.int32, (1, rows.shape[1], 1), 1)
+                rows = jnp.where(idx == pos[:, None, None], new, rows)
+            else:
+                rows = lax.dynamic_update_slice_in_dim(rows, new, pos, axis=1)
+            new_cache = {"kv": rows}
+        T = rows.shape[1]
+        mask = (L.decode_length_mask(pos, T) if S == 1
+                else L.prefill_length_mask(pos, S, T))
+    out = _latent_attend(q_nope, q_rope, rows, w_ukv, scale, mask)
+    return L.linear(out, p["wo"]), new_cache
+
+
 def make_cache(
     batch: int, n_kv_heads: int, max_len: int, head_dim: int, dtype=jnp.bfloat16
 ) -> Dict[str, jax.Array]:

@@ -115,13 +115,46 @@ def lm_head(x: jax.Array, table_or_w: jax.Array, *, transpose: bool) -> jax.Arra
 
 
 def rope_tables(
-    positions: jax.Array, head_dim: int, theta: float = 10000.0
+    positions: jax.Array, head_dim: int, theta: float = 10000.0,
+    inv_freq: Optional[np.ndarray] = None, scale: float = 1.0,
 ) -> Tuple[jax.Array, jax.Array]:
-    """cos/sin tables for positions: (..., S) -> (..., S, head_dim/2)."""
+    """cos/sin tables for positions: (..., S) -> (..., S, head_dim/2).
+
+    ``inv_freq`` (head_dim/2,) replaces the plain ``theta`` frequencies
+    (YaRN, :func:`yarn_inv_freq`); ``scale`` multiplies both tables."""
     half = head_dim // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     ang = positions.astype(jnp.float32)[..., None] * freqs  # (..., S, half)
-    return jnp.cos(ang), jnp.sin(ang)
+    if scale == 1.0:
+        return jnp.cos(ang), jnp.sin(ang)
+    return jnp.cos(ang) * scale, jnp.sin(ang) * scale
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature term: 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max_pos: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN inverse frequencies (dim/2,): the plain rope frequencies where a
+    dimension turns more than ``beta_fast`` times over ``original_max_pos``
+    positions, those divided by ``factor`` where it turns fewer than
+    ``beta_slow`` times, and a linear ramp between the two."""
+    def turn_dim(turns: float) -> float:
+        return (dim * math.log(original_max_pos / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turn_dim(beta_fast)), 0)
+    high = min(math.ceil(turn_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / (high - low), 0, 1)
+    extra = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    return (extra / factor * ramp + extra * (1.0 - ramp)).astype(np.float32)
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:

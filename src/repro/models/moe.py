@@ -1,31 +1,34 @@
-"""Mixture-of-Experts FFN: top-k routing with capacity-based dispatch.
+"""Mixture-of-Experts FFN: dropless per-token routing over a held share.
 
-The standard JAX/GSPMD-friendly MoE formulation (GShard/Switch lineage):
+One formulation serves training and serving alike:
 
-1. router logits in fp32, ``lax.top_k`` gate selection, softmax over the
-   selected k,
-2. capacity C = ⌈k·T/E · capacity_factor⌉ per expert; position-in-expert
-   via one-hot cumsum; overflowing tokens drop (weighted combine makes
-   this differentiable),
-3. scatter tokens into an (E, C, D) dispatch buffer; batched expert
-   SwiGLU via ``einsum('ecd,edf->ecf')`` — the expert axis is sharded
-   over the mesh ``model`` axis (EP), so GSPMD turns the
-   scatter/gather into all-to-all exchanges,
-4. optional shared experts (Kimi-K2 style) added densely.
+1. a float32 router scores all ``E`` routed experts (its width is the
+   published one, whatever this program holds); softmax over the ``E``
+   scores, greedy top-k, the k weights optionally renormalised and scaled;
+2. the program holds ``H <= E`` experts, ids ``first .. first + H - 1``
+   (one chip's share under expert parallelism; ``H == E`` holds all).
+   Each (token, pick) whose expert is held goes to it; nothing is dropped
+   and no capacity couples the tokens of a block, so a token's result
+   depends on that token alone;
+3. the picks are sorted by held expert and run as one grouped matmul per
+   projection (``lax.ragged_dot``), so each held expert multiplies only
+   the rows routed to it; picks of experts held elsewhere sort last and
+   are computed by no group;
+4. each token sums its held picks' outputs by weight, plus the shared
+   experts (a SwiGLU every token passes through).
 
-Expert-parallel sharding plans live in ``repro/distrib/sharding.py``.
+What the absent experts would add is left out: on one chip the layer runs
+without the expert-parallel exchange.
 """
 from __future__ import annotations
 
 import math
-from typing import Any, Dict
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
-from ..distrib.actsharding import constrain
 from . import layers as L
 
 Params = Dict[str, Any]
@@ -37,20 +40,23 @@ def moe_init(
     d_ff: int,
     n_experts: int,
     *,
+    held: int = 0,
     shared_experts: int = 0,
     shared_d_ff: int = 0,
     dtype=jnp.bfloat16,
 ) -> Params:
+    """Router over ``n_experts``; weights of ``held`` of them (0: all)."""
     ks = jax.random.split(key, 5)
+    H = held or n_experts
     scale = 1.0 / math.sqrt(d_model)
     p: Params = {
         "router": (jax.random.normal(ks[0], (d_model, n_experts)) * scale
                    ).astype(jnp.float32),
-        "w_gate": (jax.random.normal(ks[1], (n_experts, d_model, d_ff))
+        "w_gate": (jax.random.normal(ks[1], (H, d_model, d_ff))
                    * scale).astype(dtype),
-        "w_up": (jax.random.normal(ks[2], (n_experts, d_model, d_ff))
+        "w_up": (jax.random.normal(ks[2], (H, d_model, d_ff))
                  * scale).astype(dtype),
-        "w_down": (jax.random.normal(ks[3], (n_experts, d_ff, d_model))
+        "w_down": (jax.random.normal(ks[3], (H, d_ff, d_model))
                    * (1.0 / math.sqrt(d_ff))).astype(dtype),
     }
     if shared_experts:
@@ -61,99 +67,60 @@ def moe_init(
     return p
 
 
-def _positions_onehot(e_flat: jax.Array, n_experts: int) -> jax.Array:
-    """GShard-style position-in-expert via one-hot cumsum — O(T·k·E)
-    memory traffic; kept as the reference implementation."""
-    onehot = jax.nn.one_hot(e_flat, n_experts, dtype=jnp.int32)  # (T*k, E)
-    return jnp.sum(jnp.cumsum(onehot, axis=0) * onehot, axis=-1) - 1
-
-
-def _positions_sort(e_flat: jax.Array, n_experts: int) -> jax.Array:
-    """Sort-based position-in-expert — O(T·k) memory (beyond-paper §Perf
-    optimization: the one-hot cumsum materializes a (T·k, E) tensor that
-    dominates MoE HBM traffic at E=384; a stable argsort + run-rank gives
-    the identical first-come-first-served assignment)."""
-    n = e_flat.shape[0]
-    sort_idx = jnp.argsort(e_flat, stable=True)
-    se = e_flat[sort_idx]
-    run_start = jnp.searchsorted(se, se, side="left")
-    ranks = jnp.arange(n, dtype=jnp.int32) - run_start.astype(jnp.int32)
-    return jnp.zeros((n,), jnp.int32).at[sort_idx].set(ranks)
+def route(xf: jax.Array, router: jax.Array, top_k: int, *,
+          norm_topk_prob: bool = True,
+          scaling: float = 1.0) -> Tuple[jax.Array, jax.Array]:
+    """(weights (T, k) float32, expert ids (T, k)) of each token's picks."""
+    logits = jnp.einsum("td,de->te", xf.astype(jnp.float32), router,
+                        preferred_element_type=jnp.float32)
+    w, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if norm_topk_prob:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return w * scaling, idx
 
 
 def moe_ffn(
     x: jax.Array,
     p: Params,
     *,
-    n_experts: int,
     top_k: int,
-    capacity_factor: float = 1.25,
-    position_impl: str = "sort",  # 'sort' (O(Tk)) | 'onehot' (reference)
-) -> jax.Array:
-    """x: (B, S, D) -> (B, S, D)."""
+    first: int = 0,
+    norm_topk_prob: bool = True,
+    scaling: float = 1.0,
+) -> Tuple[jax.Array, jax.Array]:
+    """x: (B, S, D) -> (y (B, S, D), picks (B, S, k) int32: the held
+    expert each of a token's picks went to, ``H`` where it is held
+    elsewhere)."""
     B, S, D = x.shape
     T = B * S
+    H = p["w_gate"].shape[0]
     xf = x.reshape(T, D)
 
-    # -- routing (fp32) ------------------------------------------------------
-    logits = jnp.einsum(
-        "td,de->te", xf.astype(jnp.float32), p["router"],
-        preferred_element_type=jnp.float32,
-    )
-    top_vals, top_idx = lax.top_k(logits, top_k)  # (T, k)
-    gates = jax.nn.softmax(top_vals, axis=-1)  # normalize over selected k
+    with jax.named_scope("moe.route"):
+        w, idx = route(xf, p["router"], top_k, norm_topk_prob=norm_topk_prob,
+                       scaling=scaling)
+        local = idx - first
+        held = (local >= 0) & (local < H)
+        # held picks sorted by expert; the rest sort last, in no group
+        key = jnp.where(held, local, H).reshape(-1)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.sum(jax.nn.one_hot(key, H, dtype=jnp.int32), axis=0)
+        rows = xf[order // top_k]
 
-    # -- capacity assignment ---------------------------------------------------
-    cap = max(1, int(math.ceil(top_k * T / n_experts * capacity_factor)))
-    e_flat = top_idx.reshape(-1)  # (T*k,)
-    g_flat = gates.reshape(-1)  # (T*k,)
-    tok_idx = jnp.arange(T * top_k, dtype=jnp.int32) // top_k
+    with jax.named_scope("moe.experts"):
+        def grouped(a, wt):
+            return lax.ragged_dot(a, wt, sizes, preferred_element_type=jnp.float32)
 
-    if position_impl == "sort":
-        pos_in_e = _positions_sort(e_flat, n_experts)
-    else:
-        pos_in_e = _positions_onehot(e_flat, n_experts)
-    keep = pos_in_e < cap
-    pos_c = jnp.minimum(pos_in_e, cap - 1)
-
-    # -- dispatch: scatter tokens into (E, C, D) ---------------------------------
-    contrib = jnp.where(keep[:, None], xf[tok_idx], jnp.zeros_like(xf[tok_idx]))
-    buf = jnp.zeros((n_experts, cap, D), x.dtype)
-    buf = buf.at[e_flat, pos_c].add(contrib)
-    # pin the expert-major layout (EP): without this, token-layout pins
-    # upstream make GSPMD replicate the expert einsums (§Perf iter 2)
-    buf = constrain(buf, "moe_dispatch")
-
-    # -- batched expert SwiGLU (EP-shardable einsums) ------------------------------
-    g = jnp.einsum("ecd,edf->ecf", buf, p["w_gate"],
-                   preferred_element_type=jnp.float32).astype(x.dtype)
-    u = jnp.einsum("ecd,edf->ecf", buf, p["w_up"],
-                   preferred_element_type=jnp.float32).astype(x.dtype)
-    h = jax.nn.silu(g) * u
-    out_e = jnp.einsum("ecf,efd->ecd", h, p["w_down"],
-                       preferred_element_type=jnp.float32).astype(x.dtype)
-    out_e = constrain(out_e, "moe_dispatch")
-
-    # -- combine: gather back + gate-weighted sum ----------------------------------
-    picked = out_e[e_flat, pos_c]  # (T*k, D)
-    w = (g_flat * keep.astype(g_flat.dtype)).astype(x.dtype)[:, None]
-    y = jnp.zeros((T, D), x.dtype).at[tok_idx].add(picked * w)
+        h = (jax.nn.silu(grouped(rows, p["w_gate"]).astype(x.dtype))
+             * grouped(rows, p["w_up"]).astype(x.dtype))
+        out = grouped(h, p["w_down"])  # (T*k, D) float32, sorted
+        # back to (token, pick) order, each token's picks summed by weight
+        out = jnp.zeros_like(out).at[order].set(out).reshape(T, top_k, D)
+        wk = jnp.where(held, w, 0.0)
+        y = jnp.einsum("tkd,tk->td", jnp.where(held[..., None], out, 0.0), wk)
 
     if "shared" in p:
-        y = y + L.swiglu_ffn(xf, p["shared"])
-    return y.reshape(B, S, D)
+        with jax.named_scope("moe.shared"):
+            y = y + L.swiglu_ffn(xf, p["shared"]).astype(jnp.float32)
+    return y.astype(x.dtype).reshape(B, S, D), key.reshape(B, S, top_k)
 
-
-def aux_load_balance_loss(
-    x: jax.Array, p: Params, *, n_experts: int, top_k: int
-) -> jax.Array:
-    """Switch-style auxiliary load-balancing loss (mean_e f_e · P_e · E)."""
-    B, S, D = x.shape
-    xf = x.reshape(B * S, D).astype(jnp.float32)
-    logits = xf @ p["router"]
-    probs = jax.nn.softmax(logits, axis=-1)  # (T, E)
-    _, top_idx = lax.top_k(logits, top_k)
-    onehot = jax.nn.one_hot(top_idx, n_experts, dtype=jnp.float32).sum(1)
-    frac_routed = jnp.mean(onehot, axis=0)  # f_e
-    frac_prob = jnp.mean(probs, axis=0)  # P_e
-    return n_experts * jnp.sum(frac_routed * frac_prob)

@@ -44,8 +44,12 @@ SPANS = (
     "xla.compile",
 )
 
-#: device scopes of the model step; ``kv.*`` nest inside ``attn``
-SCOPES = ("kv.write", "kv.gather", "attn", "mlp", "logits")
+#: device scopes of the model step; ``kv.*`` and ``mla.attend`` (latent
+#: attention: absorbed scores, softmax, latent sum, W_UV) nest inside
+#: ``attn``, ``moe.*`` (routing, the held experts' grouped matmuls, the
+#: shared experts) inside ``mlp``
+SCOPES = ("kv.write", "kv.gather", "mla.attend", "attn",
+          "moe.route", "moe.experts", "moe.shared", "mlp", "logits")
 
 span = jax.profiler.TraceAnnotation
 

@@ -10,7 +10,7 @@ from _hyp import given, settings, st  # optional dep: skips when absent
 from repro.kernels import ops, ref
 from repro.kernels.rms_norm import rms_norm_pallas
 from repro.models import losses
-from repro.models.moe import _positions_onehot, _positions_sort, moe_ffn, moe_init
+from repro.models.moe import moe_ffn, moe_init
 
 
 class TestRMSNormKernel:
@@ -44,33 +44,65 @@ class TestRMSNormKernel:
                                    rtol=1e-5, atol=1e-6)
 
 
-class TestMoERouting:
-    @given(st.integers(2, 12), st.integers(10, 200), st.integers(0, 2**31))
-    @settings(max_examples=40, deadline=None)
-    def test_sort_equals_onehot(self, n_experts, n, seed):
-        rng = np.random.default_rng(seed)
-        e = jnp.asarray(rng.integers(0, n_experts, n), jnp.int32)
-        np.testing.assert_array_equal(
-            np.asarray(_positions_sort(e, n_experts)),
-            np.asarray(_positions_onehot(e, n_experts)),
-        )
+def _dense_moe(x, p, top_k, first=0, norm=True):
+    """Every held expert on every token, weighted by its picks: the plain
+    form of the dropless held-share layer."""
+    xf = x.reshape(-1, x.shape[-1])
+    probs = jax.nn.softmax(xf @ p["router"], axis=-1)
+    w, idx = jax.lax.top_k(probs, top_k)
+    if norm:
+        w = w / w.sum(-1, keepdims=True)
+    y = jnp.zeros_like(xf)
+    for e in range(p["w_gate"].shape[0]):
+        h = jax.nn.silu(xf @ p["w_gate"][e]) * (xf @ p["w_up"][e])
+        y = y + jnp.sum(jnp.where(idx == first + e, w, 0.0), -1)[:, None] * (
+            h @ p["w_down"][e])
+    return y.reshape(x.shape)
 
+
+class TestMoERouting:
     def test_moe_output_impl_invariant(self, rng):
+        """Routing is per token: a token's output is the same whether it is
+        computed in a (2, 8) block or alone."""
         key = jax.random.PRNGKey(0)
         p = moe_init(key, 16, 32, 4, dtype=jnp.float32)
         x = jnp.asarray(rng.standard_normal((2, 8, 16)).astype(np.float32))
-        a = moe_ffn(x, p, n_experts=4, top_k=2, position_impl="sort")
-        b = moe_ffn(x, p, n_experts=4, top_k=2, position_impl="onehot")
+        a, _ = moe_ffn(x, p, top_k=2)
+        b = jnp.stack([jnp.concatenate([moe_ffn(x[i:i + 1, j:j + 1], p, top_k=2)[0]
+                                        for j in range(8)], axis=1)[0]
+                       for i in range(2)])
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-6)
 
     def test_capacity_drops_tokens(self, rng):
-        """Tiny capacity factor must drop (not crash) overflow tokens."""
+        """No capacity: with every token routed to the same two experts,
+        each still gets its full weighted expert output (nothing dropped),
+        as the plain every-expert form computes it."""
         key = jax.random.PRNGKey(0)
         p = moe_init(key, 8, 16, 2, dtype=jnp.float32)
         x = jnp.asarray(rng.standard_normal((1, 32, 8)).astype(np.float32))
-        out = moe_ffn(x, p, n_experts=2, top_k=2, capacity_factor=0.25)
-        assert np.all(np.isfinite(np.asarray(out)))
+        out, picks = moe_ffn(x, p, top_k=2)
+        assert np.all(np.sort(np.asarray(picks), -1) == [0, 1])
+        np.testing.assert_allclose(np.asarray(out), np.asarray(_dense_moe(x, p, 2)),
+                                   rtol=1e-5, atol=1e-5)
+
+    def test_held_share_matches_plain_form(self, rng):
+        """Holding experts 2-4 of 6 (no renormalisation), the grouped
+        matmul over the held picks equals the plain every-held-expert form,
+        and the picks name the held expert (0-2 for ids 2-4, 3 elsewhere)."""
+        key = jax.random.PRNGKey(1)
+        full = moe_init(key, 8, 16, 6, dtype=jnp.float32)
+        p = dict(full, w_gate=full["w_gate"][2:5], w_up=full["w_up"][2:5],
+                 w_down=full["w_down"][2:5])
+        x = jnp.asarray(rng.standard_normal((2, 5, 8)).astype(np.float32))
+        out, picks = moe_ffn(x, p, top_k=3, first=2, norm_topk_prob=False)
+        want = _dense_moe(x, p, 3, first=2, norm=False)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+        _, idx = jax.lax.top_k(jax.nn.softmax(x.reshape(10, 8) @ p["router"]), 3)
+        idx = np.asarray(idx)
+        np.testing.assert_array_equal(np.asarray(picks).reshape(10, 3),
+                                      np.where((idx >= 2) & (idx < 5), idx - 2, 3))
 
     def test_moe_grads(self, rng):
         key = jax.random.PRNGKey(0)
@@ -78,7 +110,7 @@ class TestMoERouting:
         x = jnp.asarray(rng.standard_normal((1, 8, 8)).astype(np.float32))
 
         def loss(p):
-            return jnp.sum(moe_ffn(x, p, n_experts=4, top_k=2) ** 2)
+            return jnp.sum(moe_ffn(x, p, top_k=2)[0] ** 2)
 
         g = jax.grad(loss)(p)
         assert all(np.all(np.isfinite(np.asarray(l, np.float32)))

@@ -60,7 +60,8 @@ class TestSmokeForward:
         assert np.all(np.isfinite(lo))
         # cache must have been written (not all zeros anymore) for attn archs
         if cfg.family in ("dense", "moe", "vlm"):
-            assert float(jnp.sum(jnp.abs(cache2["k"]))) > 0
+            assert any(float(jnp.sum(jnp.abs(leaf.astype(jnp.float32)))) > 0
+                       for leaf in jax.tree_util.tree_leaves(cache2))
 
     def test_train_grad_finite(self, arch_setup):
         cfg, model, params, tokens = arch_setup
@@ -128,7 +129,7 @@ class TestConfigs:
         assert not shape_applicable(get_config("deepseek-7b"), "long_500k")[0]
 
     def test_registry_complete(self):
-        assert len(ARCH_IDS) == 10
+        assert len(ARCH_IDS) == 11
 
     def test_moe_active_params(self):
         cfg = get_config("kimi-k2-1t-a32b")

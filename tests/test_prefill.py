@@ -132,21 +132,29 @@ class TestServePrefillGrid:
         assert rb["ttft_s"] > 0 and rs["ttft_s"] > 0
 
     def test_moe_family_has_no_batched_prefill(self):
-        """MoE capacity routing couples tokens across the flattened
-        (B, S) block, so whole-prompt prefill would silently diverge
-        from sequential decode — the family must expose no prefill_step
-        and serve through the sequential path."""
+        """MoE routing is dropless and per token, so a whole-prompt block
+        reproduces sequential decode: the family exposes prefill_step, the
+        serve fronts build the batched prefill, and the block's logits
+        equal token-by-token decode (float32; the tolerance covers the
+        matmuls' different row blocking, routing picks the same experts)."""
         from repro.launch.steps import make_batched_prefill_step
         from repro.models import transformer
 
-        cfg = get_config("phi3.5-moe-42b-a6.6b", smoke=True)
+        cfg = get_config("phi3.5-moe-42b-a6.6b", smoke=True).with_(dtype="float32")
         assert cfg.family == "moe"
-        assert not transformer.supports_batched_prefill(cfg)
-        assert get_model(cfg).prefill_step is None
-        assert make_batched_prefill_step(cfg) is None
-        # direct module callers hit the mechanism-level guard too
-        with pytest.raises(NotImplementedError, match="capacity routing"):
-            transformer.prefill_step(None, None, None, None, cfg)
+        model = get_model(cfg)
+        assert model.prefill_step is transformer.prefill_step
+        assert make_batched_prefill_step(cfg) is not None
+        params = model.init(jax.random.PRNGKey(2), cfg)
+        toks = jnp.asarray(_prompts(2, 7, seed=6), jnp.int32)
+        cache = model.init_cache(cfg, 2, 16)
+        block, _ = model.prefill_step(params, cache, toks, jnp.int32(0), cfg)
+        steps = []
+        for t in range(7):
+            lg, cache = model.decode_step(params, cache, toks[:, t:t + 1], jnp.int32(t), cfg)
+            steps.append(lg[:, 0])
+        np.testing.assert_allclose(np.asarray(block), np.stack(steps, 1),
+                                   rtol=1e-4, atol=1e-4)
 
     def test_prompt_beyond_ladder_falls_back(self, smoke_setup):
         """A prompt longer than the top sequence rung (or than max_len)

@@ -446,16 +446,17 @@ class TestXlstmChunkedPrefill:
 class TestServeIntegration:
     def test_supports_batched_prefill_predicate(self, rglru_setup,
                                                 xlstm_setup):
-        """The single serve-front predicate now admits the recurrent
-        families (and the step builders follow it)."""
+        """The single serve-front predicate admits the recurrent families
+        and MoE (and the step builders follow it)."""
         for cfg, model, _ in (rglru_setup, xlstm_setup):
             assert supports_batched_prefill(cfg)
             assert model.prefill_takes_length
             assert make_batched_prefill_step(cfg) is not None
             assert make_slot_prefill_step(cfg) is not None
+        # MoE routing is dropless and per token: it joins the same grid
         moe = get_config("phi3.5-moe-42b-a6.6b", smoke=True)
-        assert not supports_batched_prefill(moe)
-        assert make_slot_prefill_step(moe) is None
+        assert supports_batched_prefill(moe)
+        assert make_slot_prefill_step(moe) is not None
 
     @pytest.mark.parametrize("name", ["recurrentgemma-2b", "xlstm-350m"])
     def test_generate_chunked_matches_sequential(self, name):
